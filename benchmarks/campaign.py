@@ -85,4 +85,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.obs.jaxprof import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -407,6 +407,8 @@ def main(quick: bool = True, out: str = None,
 
 
 if __name__ == "__main__":
+    from repro.obs.jaxprof import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true",
                     help=">=50k-node GNMT-8 + deep WaveNet/Transformer-XL")

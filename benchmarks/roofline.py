@@ -355,4 +355,6 @@ def cli(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    from repro.obs.jaxprof import enable_compile_cache
+    enable_compile_cache()
     cli()

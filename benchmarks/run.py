@@ -225,4 +225,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.obs.jaxprof import enable_compile_cache
+    enable_compile_cache()
     main()

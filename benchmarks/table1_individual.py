@@ -62,4 +62,6 @@ def main(quick: bool = True):
 
 
 if __name__ == "__main__":
+    from repro.obs.jaxprof import enable_compile_cache
+    enable_compile_cache()
     main(quick=False)

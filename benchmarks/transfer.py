@@ -226,6 +226,8 @@ def main(quick: bool = True, out: str = None) -> Dict[str, Any]:
 
 
 if __name__ == "__main__":
+    from repro.obs.jaxprof import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--out", default=None,

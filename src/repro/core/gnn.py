@@ -14,6 +14,7 @@ The neighbor max-aggregation is the per-step hot spot on 50k-node graphs;
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import Any, Dict, Optional
 
 import jax
@@ -22,6 +23,7 @@ import jax.numpy as jnp
 from repro.core import nn
 from repro.core.featurize import GraphBatch, NUM_NUMERIC_FEATURES
 from repro.core.graph import NUM_OP_TYPES
+from repro.obs import jaxprof
 
 NEG = -1e9
 
@@ -79,16 +81,27 @@ def _neighbor_max(z: jnp.ndarray, nbr_idx: jnp.ndarray, nbr_mask: jnp.ndarray,
         return kops.neighbor_maxpool_csr(z, csr_blocks,
                                          num_rows=z.shape[0])
     z_pad = jnp.concatenate([z, jnp.full((1, z.shape[1]), NEG, z.dtype)])
-    n, k = nbr_idx.shape
-    if chunk is None or n <= chunk:
+    if chunk is None or nbr_idx.shape[0] <= chunk:
         return _gather_max(z_pad, nbr_idx, nbr_mask)
+    return _chunked_gather_max(z_pad, nbr_idx, nbr_mask, chunk)
+
+
+# jitted so an eager caller (the segmented PPO path runs the GNN outside
+# any jit) reuses one compiled program: an eager ``lax.map`` over a fresh
+# closure compiles a new scan on every call
+@partial(jax.jit, static_argnames=("chunk",))
+def _chunked_gather_max(z_pad, nbr_idx, nbr_mask, chunk: int):
+    n, k = nbr_idx.shape
     pad = (-n) % chunk
     idx = jnp.pad(nbr_idx, ((0, pad), (0, 0)), constant_values=n)
     mask = jnp.pad(nbr_mask, ((0, pad), (0, 0)))
     agg = jax.lax.map(
         lambda im: _gather_max(z_pad, im[0], im[1]),
         (idx.reshape(-1, chunk, k), mask.reshape(-1, chunk, k)))
-    return agg.reshape(-1, z.shape[1])[:n]
+    return agg.reshape(-1, z_pad.shape[1])[:n]
+
+
+jaxprof.register("gnn.chunked_gather_max", _chunked_gather_max)
 
 
 def apply(params: Dict[str, Any], gb: GraphBatch, *, agg_impl: str = "jnp",

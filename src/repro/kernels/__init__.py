@@ -4,5 +4,8 @@
 #                        prefill/train attention and the GDP placer.
 #   segment_maxpool.py — GraphSAGE neighbor max aggregation as blocked
 #                        masked-adjacency max (TPU-native; DESIGN.md §3).
-# ops.py = jit'd dispatch wrappers (interpret=True off-TPU);
+#   band_attention.py  — block-sparse banded attention (segmented TF pass).
+#   csr_maxpool.py     — BSR-blocked neighbor max-pool (scalar-prefetched
+#                        tile ids; bytes scale with edges).
+# ops.py = jit'd dispatch wrappers (interpret mode off-TPU: ops.interpret);
 # ref.py = pure-jnp oracles anchoring tests/test_kernels.py.

@@ -31,8 +31,9 @@ Band geometry (one mechanism covers every caller):
   diag_hi = T, kv_len = real T`` — the kv_len mask is what keeps padded
   keys out of the softmax.
 
-Oracle: ``repro.kernels.ref.band_attention_ref``; CPU validation uses
-interpret=True (tests/test_kernels.py property net).
+Oracle: ``repro.kernels.ref.band_attention_ref``; off-TPU validation runs
+in interpret mode (tests/test_kernels.py property net), and
+tests/test_tpu_compile.py compiles it for a v5e.
 """
 from __future__ import annotations
 
@@ -53,9 +54,7 @@ def _band_kernel(lo_ref, q_ref, k_ref, v_ref, o_ref, *, sm_scale: float,
     q = q_ref[0].astype(jnp.float32) * sm_scale          # [bq, d]
     bq, d = q.shape
     nk = seq_k // block_k
-    # dynamic valid-column floor (first-segment memory masking); slice-only
-    # indexers as in flash_attention (interpret-mode discharge on 0.4.3x)
-    kv_lo = pl.load(lo_ref, (pl.dslice(0, 1),))[0]
+    kv_lo = lo_ref[0]          # dynamic valid-column floor (first segment)
     row0 = qi * block_q
     rows = row0 + jax.lax.iota(jnp.int32, block_q)
 
@@ -69,12 +68,8 @@ def _band_kernel(lo_ref, q_ref, k_ref, v_ref, o_ref, *, sm_scale: float,
 
     def body(j, carry):
         acc, m_run, l_run = carry
-        k_blk = pl.load(k_ref, (pl.dslice(0, 1),
-                                pl.dslice(j * block_k, block_k),
-                                slice(None)))[0].astype(jnp.float32)
-        v_blk = pl.load(v_ref, (pl.dslice(0, 1),
-                                pl.dslice(j * block_k, block_k),
-                                slice(None)))[0].astype(jnp.float32)
+        k_blk = k_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
+        v_blk = v_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
         s = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())))  # [bq,bk]
         cols = j * block_k + jax.lax.iota(jnp.int32, block_k)
         delta = cols[None, :] - rows[:, None]

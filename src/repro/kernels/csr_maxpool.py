@@ -12,22 +12,21 @@ time by :func:`build_block_index`):
 * ``col_blocks``: i32[nR, T] — for row-block ``r``, the column-block ids
   holding at least one neighbor edge, sentinel ``-1`` padded to the max
   tile count ``T`` (one compiled shape per graph).
-* ``adj``: bool[nR, T, bn, bm] — the densified tiles themselves, in the
-  same order.
+* ``adj``: int8[nR, T, bn, bm] — the densified 0/1 tiles themselves, in
+  the same order.
 
 The grid is (row-block, feature-block, tile); the innermost axis walks the
 row-block's tile list and accumulates a running max in the revisited
-output tile, exactly the ``segment_maxpool`` accumulation pattern.  A
-sentinel tile is skipped under ``pl.when``, so the inner trip count is
-``T`` but the *bytes touched* are proportional to the true tile count
-(:func:`nnz_blocks` — the roofline's modeled-bytes source).
-
-TPU NOTE: this interpret-mode implementation keeps the full ``z`` in one
-VMEM block and slices the ``[bm, bh]`` feature tile with a dynamic-start
-``pl.dslice`` (data-dependent column block).  On a real TPU the same
-index drives a ``PrefetchScalarGridSpec`` scalar-prefetch ``index_map``
-instead, so only the referenced tile crosses HBM→VMEM; the format and
-kernel body are unchanged.  CPU tests run with interpret=True.
+output tile, exactly the ``segment_maxpool`` accumulation pattern.
+``col_blocks`` (flattened) and the per-row tile count are scalar-prefetched
+into SMEM (``PrefetchScalarGridSpec``), so the ``z`` index_map fetches only
+the referenced ``[bm, bh]`` feature tile HBM→VMEM.  Sentinel steps re-use
+the row's last real tile index — the pipeline skips the copy when the
+block index does not change — and are skipped under ``pl.when``, so the
+inner trip count is ``T`` but the *bytes touched* are proportional to the
+true tile count (:func:`nnz_blocks` — the roofline's modeled-bytes
+source).  The mask tile is int8 and is applied one adjacency column at a
+time as a 2-D ``[bn, bh]`` select, so no 3-D broadcast is formed.
 
 Oracle: ``repro.kernels.ref.neighbor_maxpool_from_lists_ref`` (same
 padded-neighbor-list inputs the index is built from).
@@ -41,6 +40,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG = -1e9
 
@@ -52,7 +52,7 @@ class BlockIndex(NamedTuple):
     tuple jit-flattens to two arrays and nothing retraces on value changes.
     """
     col_blocks: jnp.ndarray   # i32[nR, T], sentinel -1
-    adj: jnp.ndarray          # bool[nR, T, bn, bm]
+    adj: jnp.ndarray          # int8[nR, T, bn, bm], 1 = edge
 
 
 def build_block_index(nbr_idx, nbr_mask, num_cols: int, *,
@@ -83,7 +83,7 @@ def build_block_index(nbr_idx, nbr_mask, num_cols: int, *,
         per_row.append(tiles)
     t_max = max(1, max(len(t) for t in per_row))
     col_blocks = np.full((n_row_blocks, t_max), -1, np.int32)
-    adj = np.zeros((n_row_blocks, t_max, block_n, block_m), bool)
+    adj = np.zeros((n_row_blocks, t_max, block_n, block_m), np.int8)
     for r, tiles in enumerate(per_row):
         for t, (c, tile) in enumerate(sorted(tiles.items())):
             col_blocks[r, t] = c
@@ -97,47 +97,54 @@ def nnz_blocks(blocks: BlockIndex) -> int:
     return int((np.asarray(blocks.col_blocks) >= 0).sum())
 
 
-def _csr_kernel(cb_ref, adj_ref, z_ref, o_ref, *, block_m: int):
-    t = pl.program_id(2)
+def _csr_kernel(cb_ref, nt_ref, adj_ref, z_ref, o_ref):
+    r, t = pl.program_id(0), pl.program_id(2)
 
     @pl.when(t == 0)
     def _init():
         o_ref[...] = jnp.full_like(o_ref, NEG)
 
-    # NB: slice-only indexers (pl.dslice, never a bare int) — integer
-    # indexers break interpret-mode state discharge on jax 0.4.3x.
-    cb = pl.load(cb_ref, (pl.dslice(0, 1), pl.dslice(t, 1)))[0, 0]
-
-    @pl.when(cb >= 0)
+    @pl.when(t < nt_ref[r])
     def _accumulate():
-        adj = pl.load(adj_ref, (pl.dslice(0, 1), pl.dslice(0, 1),
-                                slice(None), slice(None)))[0, 0]   # [bn, bm]
-        z = pl.load(z_ref, (pl.dslice(cb * block_m, block_m),
-                            slice(None))).astype(jnp.float32)      # [bm, bh]
-        masked = jnp.where(adj[:, :, None], z[None, :, :], NEG)
-        o_ref[...] = jnp.maximum(o_ref[...],
-                                 masked.max(axis=1).astype(o_ref.dtype))
+        adj = adj_ref[...].astype(jnp.float32)                 # [bn, bm]
+        z = z_ref[...].astype(jnp.float32)                     # [bm, bh]
+        acc = o_ref[...].astype(jnp.float32)                   # [bn, bh]
+        for j in range(adj.shape[1]):
+            acc = jnp.maximum(acc, jnp.where(adj[:, j:j + 1] > 0,
+                                             z[j:j + 1, :], NEG))
+        o_ref[...] = acc.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_h", "interpret"))
 def _csr_call(z, col_blocks, adj, *, block_h: int, interpret: bool):
     n_row_blocks, t_max, bn, bm = adj.shape
-    m, h = z.shape
+    h = z.shape[1]
     bh = min(block_h, h)
-    grid = (n_row_blocks, h // bh, t_max)        # t innermost: accumulation
-    kernel = functools.partial(_csr_kernel, block_m=bm)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
+    n_tiles = (col_blocks >= 0).sum(axis=1).astype(jnp.int32)      # [nR]
+
+    def tile(r, t, nt):
+        # sentinel steps re-point at the row's last real tile (no new DMA)
+        return jnp.maximum(jnp.minimum(t, nt[r] - 1), 0)
+
+    def z_map(r, hh, t, cb, nt):
+        return jnp.maximum(cb[r * t_max + tile(r, t, nt)], 0), hh
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(n_row_blocks, h // bh, t_max),       # t innermost: accumulation
         in_specs=[
-            pl.BlockSpec((1, t_max), lambda r, hh, t: (r, 0)),
-            pl.BlockSpec((1, 1, bn, bm), lambda r, hh, t: (r, t, 0, 0)),
-            pl.BlockSpec((m, bh), lambda r, hh, t: (0, hh)),
+            pl.BlockSpec((None, None, bn, bm),
+                         lambda r, hh, t, cb, nt: (r, tile(r, t, nt), 0, 0)),
+            pl.BlockSpec((bm, bh), z_map),
         ],
-        out_specs=pl.BlockSpec((bn, bh), lambda r, hh, t: (r, hh)),
+        out_specs=pl.BlockSpec((bn, bh), lambda r, hh, t, cb, nt: (r, hh)),
+    )
+    return pl.pallas_call(
+        _csr_kernel,
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_row_blocks * bn, h), z.dtype),
         interpret=interpret,
-    )(col_blocks, adj, z)
+    )(col_blocks.reshape(-1), n_tiles, adj, z)
 
 
 def neighbor_maxpool_csr(z: jnp.ndarray, blocks: BlockIndex, *,

@@ -8,7 +8,7 @@ prefill/train attention and by the GDP placer's segment attention; the
 pure-jnp oracle is ``repro.kernels.ref.flash_attention_ref`` and the
 dry-run lowers the XLA-native twin (``models.layers.chunked_attention``).
 
-VALIDATED on CPU with ``interpret=True`` over shape/dtype sweeps
+Validated against the oracle in interpret mode over shape/dtype sweeps
 (tests/test_kernels.py).
 """
 from __future__ import annotations
@@ -47,12 +47,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, sm_scale: float,
 
     def body(j, carry):
         acc, m_run, l_run = carry
-        # NB: slice-only indexers (pl.dslice, never a bare int) — integer
-        # indexers break interpret-mode state discharge on jax 0.4.3x.
-        k_blk = pl.load(k_ref, (pl.dslice(0, 1), pl.dslice(j * block_k, block_k),
-                                slice(None)))[0].astype(jnp.float32)
-        v_blk = pl.load(v_ref, (pl.dslice(0, 1), pl.dslice(j * block_k, block_k),
-                                slice(None)))[0].astype(jnp.float32)
+        k_blk = k_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
+        v_blk = v_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
         s = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())))  # [bq,bk]
         k_pos = j * block_k + jax.lax.iota(jnp.int32, block_k)
         # padded-key guard: keys at/after the true length never reach the

@@ -2,9 +2,10 @@
 
 These are the entry points the rest of the framework calls
 (``gnn.apply(agg_impl="pallas")``, ``placer`` attention, model-zoo hot
-paths).  On a TPU backend they run the compiled kernels; on CPU they run
-interpret=True (exact same kernel body, Python-evaluated) so tests and the
-GDP training loop behave identically everywhere.
+paths).  On a TPU backend they run the compiled kernels; on any other
+backend they run in interpret mode (the same kernel body, evaluated by
+XLA) so tests and the GDP training loop behave identically everywhere.
+:func:`interpret` is the one place that choice is made.
 """
 from __future__ import annotations
 
@@ -42,7 +43,7 @@ def _int_zeros(x):
 def _band_call(q, k, v, kv_lo, diag_lo, diag_hi, kv_len, block_q, block_k):
     return band_attention(q, k, v, kv_lo, diag_lo=diag_lo, diag_hi=diag_hi,
                           kv_len=kv_len, block_q=block_q, block_k=block_k,
-                          interpret=not _on_tpu())
+                          interpret=interpret())
 
 
 def _band_call_fwd(q, k, v, kv_lo, diag_lo, diag_hi, kv_len, block_q,
@@ -68,7 +69,7 @@ _band_call.defvjp(_band_call_fwd, _band_call_bwd)
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _csr_diff(z, col_blocks, adj, num_rows):
     return _csr(z, BlockIndex(col_blocks, adj), num_rows=num_rows,
-                interpret=not _on_tpu())
+                interpret=interpret())
 
 
 def _csr_diff_fwd(z, col_blocks, adj, num_rows):
@@ -86,8 +87,9 @@ def _csr_diff_bwd(num_rows, res, ct):
 _csr_diff.defvjp(_csr_diff_fwd, _csr_diff_bwd)
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def interpret() -> bool:
+    """Run Pallas kernels in interpret mode: everywhere but on a TPU."""
+    return jax.default_backend() != "tpu"
 
 
 def _pad_to(x: jnp.ndarray, axis: int, mult: int):
@@ -128,7 +130,7 @@ def neighbor_maxpool(z: jnp.ndarray, nbr_idx: jnp.ndarray,
                        constant_values=zp.shape[0])
         maskp = jnp.pad(nbr_mask, ((0, pad_n), (0, 0)))
         out = neighbor_maxpool_chunked(zp.astype(jnp.float32), idxp, maskp,
-                                       chunk=chunk, interpret=not _on_tpu())
+                                       chunk=chunk, interpret=interpret())
     else:
         # densify the padded neighbor lists into an adjacency bitmask
         onehot = (nbr_idx[..., None] ==
@@ -137,7 +139,7 @@ def neighbor_maxpool(z: jnp.ndarray, nbr_idx: jnp.ndarray,
         adjp, _ = _pad_to(adj, 0, 64)
         adjp, _ = _pad_to(adjp, 1, 128)
         out = neighbor_maxpool_dense(zp.astype(jnp.float32), adjp,
-                                     interpret=not _on_tpu())
+                                     interpret=interpret())
     out = out[:n, :h]
     return jnp.where(out <= NEG / 2, 0.0, out).astype(z.dtype)
 
@@ -171,7 +173,7 @@ def mha_with_memory(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     else:
         out = flash_attention(qp, kp, vp, causal=False, kv_len=t,
                               block_q=bq, block_k=bk,
-                              interpret=not _on_tpu())
+                              interpret=interpret())
     return out[:, :sq0].transpose(1, 0, 2)
 
 
@@ -199,7 +201,7 @@ def causal_window_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     else:
         out = flash_attention(qp, kp, vp, causal=True, window=window,
                               q_offset=q_offset, kv_len=s0,
-                              block_q=b, block_k=b, interpret=not _on_tpu())
+                              block_q=b, block_k=b, interpret=interpret())
     return out[:, :s0]
 
 

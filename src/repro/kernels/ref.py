@@ -78,23 +78,36 @@ def neighbor_maxpool_from_lists_ref(z, nbr_idx, nbr_mask) -> jnp.ndarray:
     return masked.max(axis=1)
 
 
+CSR_ROWS_PER_STEP = 8     # row blocks per checkpointed step of the oracle
+
+
 def csr_maxpool_blocks_ref(z, col_blocks, adj) -> jnp.ndarray:
     """BSR-index form of the max-pool oracle (same inputs as the kernel).
 
-    z: [M, H]; col_blocks: i32[nR, T] (sentinel -1); adj: bool[nR, T, bn,
+    z: [M, H]; col_blocks: i32[nR, T] (sentinel -1); adj: int8[nR, T, bn,
     bm] -> [nR*bn, H] with -1e9 for rows without neighbors — the raw
     kernel contract, before the ops wrapper zeroes isolates.  Pure jnp and
     differentiable: this is the backward path of the CSR kernel's
-    custom_vjp (it materializes [nR, T, bn, bm, H] tile outer products, so
-    it is a training-scale path, not a 50k-inference one).
+    custom_vjp.  Row blocks are mapped ``CSR_ROWS_PER_STEP`` at a time under
+    ``jax.checkpoint``, so forward and backward hold one step's
+    ``[rows, T, bn, bm, H]`` tile outer product, never the whole graph's
+    (at the 50k-node GNMT-8 cell that product is ~20 GB).
     """
     n_r, t_max, bn, bm = adj.shape
     m, h = z.shape
     pad_m = (-m) % bm
     zp = jnp.concatenate([z, jnp.zeros((pad_m, h), z.dtype)]) if pad_m else z
     tiles = zp.reshape(zp.shape[0] // bm, bm, h)
-    zsel = tiles[jnp.clip(col_blocks, 0, tiles.shape[0] - 1)]  # [nR,T,bm,H]
-    ok = (col_blocks >= 0)[:, :, None, None] & adj             # [nR,T,bn,bm]
-    masked = jnp.where(ok[..., None],
-                       zsel[:, :, None, :, :].astype(jnp.float32), -1e9)
-    return masked.max(axis=(1, 3)).reshape(n_r * bn, h).astype(z.dtype)
+
+    @jax.checkpoint
+    def row_block(args):
+        cb, a = args                                           # [T], [T,bn,bm]
+        zsel = tiles[jnp.clip(cb, 0, tiles.shape[0] - 1)]      # [T, bm, H]
+        ok = (cb >= 0)[:, None, None] & (a > 0)                # [T, bn, bm]
+        masked = jnp.where(ok[..., None],
+                           zsel[:, None, :, :].astype(jnp.float32), -1e9)
+        return masked.max(axis=(0, 2))                         # [bn, H]
+
+    out = jax.lax.map(row_block, (col_blocks, adj),
+                      batch_size=CSR_ROWS_PER_STEP)
+    return out.reshape(n_r * bn, h).astype(z.dtype)

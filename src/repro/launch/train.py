@@ -16,28 +16,20 @@ LM mode (sanity-scale zoo training on CPU):
   pipeline; on TPU the same step functions drive the full configs through
   jit with the sharding rules in repro/dist (see dryrun.py).
 
-Scale-out notes (1000+ nodes) are in DESIGN.md §6: XLA latency-hiding
-scheduler flags are set here; gradient compression hooks live in
-repro/optim/compress.py; elastic restarts re-shard checkpoints onto the
-current mesh.
+Scale-out notes (1000+ nodes) are in DESIGN.md §6: gradient compression
+hooks live in repro/optim/compress.py; elastic restarts re-shard
+checkpoints onto the current mesh.
 """
 from __future__ import annotations
 
 import argparse
-import dataclasses
-import os
 import signal
-import sys
 import time
 
 import jax
 import numpy as np
 
-# collective/compute overlap on real backends (no-op on CPU)
-os.environ.setdefault(
-    "LIBTPU_INIT_ARGS",
-    "--xla_tpu_enable_latency_hiding_scheduler=true "
-    "--xla_tpu_enable_async_collective_fusion=true")
+from repro.obs import jaxprof
 
 
 def train_gdp(args) -> None:
@@ -146,6 +138,7 @@ def main() -> None:
     ap.add_argument("--lm-batch", type=int, default=8)
     ap.add_argument("--lm-seq", type=int, default=64)
     args = ap.parse_args()
+    jaxprof.enable_compile_cache()
     if args.mode == "gdp":
         train_gdp(args)
     else:

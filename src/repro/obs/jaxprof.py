@@ -25,12 +25,36 @@ Peak-RSS sampling lives here too (:func:`peak_rss_bytes`) — it is the
 ``ru_maxrss`` helper benchmarks have used since PR 1, relocated so every
 telemetry consumer shares one definition; ``benchmarks/common`` now
 delegates to it.
+
+:func:`enable_compile_cache` is the one place the persistent compilation
+cache is configured; every command-line entry point calls it first.
 """
 from __future__ import annotations
 
+import os
 import resource
 import sys
 from typing import Any, Callable, Dict, Optional
+
+# <repo>/.cache/jax (gitignored): a fixed path, because the cache key
+# includes it — a directory that moves between runs never hits
+REPO_CACHE_DIR = os.path.abspath(os.path.join(
+    os.path.dirname(__file__), "..", "..", "..", ".cache", "jax"))
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself and
+    nothing is changed here; otherwise the cache lives at
+    :data:`REPO_CACHE_DIR`.  Call before the first compile.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    jax.config.update("jax_compilation_cache_dir", REPO_CACHE_DIR)
+    return REPO_CACHE_DIR
 
 # ---------------------------------------------------------------- registry
 # name -> jitted callable.  Keyed by explicit name (module-qualified by

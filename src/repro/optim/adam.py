@@ -8,10 +8,13 @@ always performed in float32.
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+
+from repro.obs import jaxprof
 
 
 @dataclasses.dataclass(frozen=True)
@@ -44,6 +47,10 @@ def adam_init(params, cfg: AdamConfig) -> AdamState:
                      nu=_cast(zeros, cfg.state_dtype))
 
 
+# jitted so an eagerly-run update (the segmented PPO path) compiles once:
+# eager ``b1 ** step`` with a concrete step would compile a new
+# integer_pow program for every step value
+@partial(jax.jit, static_argnames=("cfg",))
 def adam_update(grads, state: AdamState, params, cfg: AdamConfig,
                 lr_scale: jnp.ndarray | float = 1.0):
     step = state.step + 1
@@ -69,3 +76,6 @@ def adam_update(grads, state: AdamState, params, cfg: AdamConfig,
     new_nu = jax.tree_util.tree_map(lambda t: t[2], out,
                                     is_leaf=lambda t: isinstance(t, tuple))
     return new_params, AdamState(step=step, mu=new_mu, nu=new_nu)
+
+
+jaxprof.register("optim.adam_update", adam_update)

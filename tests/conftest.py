@@ -5,10 +5,8 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-# The target container has no `hypothesis` and forbids installing one; CI
-# installs the real package via the `dev` extra.  Fall back to the
-# deterministic shim only when the real library is absent so the property
-# tests still collect and run everywhere.
+# The `dev` extra installs `hypothesis`; where it is absent, fall back to
+# the deterministic shim so the property tests still collect and run.
 try:
     import hypothesis  # noqa: F401
 except ModuleNotFoundError:

@@ -5,9 +5,10 @@ changes results — only compiled shapes.  These tests pin it bit-for-bit
 on small golden graphs across both contention modes and uniform + hetero
 topologies, plus the serving-tier jumbo admission/rejection paths.
 
-(The teacher-forced pins compare the *jitted* monolithic pass against the
-segmented pass: both production paths are compiled, and XLA's eager
-dispatch rounds a few ULP differently than its fused programs.)
+(The teacher-forced logits are the one tolerance pin: the segmented pass
+multiplies [segment, H] blocks where the monolithic pass multiplies one
+[N, H] block, and XLA picks its dot kernels by shape, so the two round a
+few ULP apart.)
 """
 import dataclasses
 from functools import partial
@@ -81,9 +82,10 @@ def test_sample_segmented_bitwise_hetero(setup):
 # ------------------------------------------------------- teacher-forced
 @pytest.mark.parametrize("seg", [8, 16, 64])
 def test_tf_segmented_bitwise(setup, seg):
-    """Segmented teacher-forced logits == jitted monolithic logits,
-    bit-for-bit, for any segment size (the Transformer-XL memory hands
-    each node exactly the W-band the banded pass gathers)."""
+    """Segmented teacher-forced logits == jitted monolithic logits to f32
+    rounding, for any segment size (the Transformer-XL memory hands each
+    node exactly the W-band the banded pass gathers; only the dot
+    kernels' accumulation order differs with the block shape)."""
     _, gb, params = setup
     h = gnn.apply(params["gnn"], gb)
     from repro.core import superposition
@@ -100,7 +102,8 @@ def test_tf_segmented_bitwise(setup, seg):
                                  gb.mem_frac, gb.comp_frac, gb.dev_feats,
                                  segment=seg, window=CFG.window,
                                  heads=CFG.heads, num_devices=4)
-    assert np.array_equal(np.asarray(lg_m), np.asarray(lg_s))
+    np.testing.assert_allclose(np.asarray(lg_s), np.asarray(lg_m),
+                               rtol=1e-6, atol=1e-6)
 
 
 def test_logp_segmented_matches_monolithic(setup):

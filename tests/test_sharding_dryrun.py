@@ -21,7 +21,8 @@ import jax, jax.numpy as jnp
 from repro.configs import get_reduced
 from repro.models.model import build_model
 from repro.dist import sharding as SH
-mesh = jax.make_mesh((4, 4), ("data", "model"))
+mesh = jax.make_mesh((4, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cfg = get_reduced("qwen3-8b")
 model = build_model(cfg)
 state_sh = jax.eval_shape(lambda: model.init_train_state(jax.random.PRNGKey(0)))
