@@ -73,29 +73,14 @@ class IterLog:
     """``PPOTrainer.run_log`` sink: stamps each PPO iteration once the
     updated parameters are on the device (``block_until_ready``)."""
 
-    def __init__(self, trainer, counter):
-        self.trainer, self.counter = trainer, counter
-        self.records, self.stamps, self.compiles = [], [], []
+    def __init__(self, trainer):
+        self.trainer = trainer
+        self.records, self.stamps = [], []
 
     def emit(self, rec) -> None:
         jax.block_until_ready(self.trainer.state.params)
         self.stamps.append(time.perf_counter())
-        self.compiles.append(self.counter.n)
         self.records.append(rec)
-
-
-class CompileCounter:
-    """Counts XLA backend compiles (every program, eager ops included)."""
-
-    EVENT = "/jax/core/compile/backend_compile_duration"
-
-    def __init__(self):
-        self.n = 0
-        jax.monitoring.register_event_duration_secs_listener(self._on)
-
-    def _on(self, event, duration, **kwargs) -> None:
-        if event == self.EVENT:
-            self.n += 1
 
 
 # ------------------------------------------------------------------ phase 0
@@ -119,7 +104,7 @@ def phase0_device():
 
 
 # ------------------------------------------------------------------ phase 1
-def phase1_main_path(g, counter):
+def phase1_main_path(g):
     from benchmarks.large_graph import (LARGE_SCALE, SLACK, large_policy,
                                         large_ppo)
     from repro.api import Budget, place
@@ -135,7 +120,7 @@ def phase1_main_path(g, counter):
         g.total_mem() / NUM_DEVICES * SLACK)
     pcfg, ppo = large_policy(), large_ppo(SAMPLES)
     tr = PPOTrainer(pcfg, ppo, seed=0)
-    it_log = IterLog(tr, counter)
+    it_log = IterLog(tr)
     tr.run_log = it_log
     t0 = time.perf_counter()
     plan = place(g, topo, method="finetune", pcfg=pcfg, ppo=ppo,
@@ -148,16 +133,15 @@ def phase1_main_path(g, counter):
     setup_s = stamps[0] - t0
     steady = np.diff(stamps)
     new_programs = sum(r["retraces"] for r in recs[1:])
+    steady_compiles = sum(r["compiles"] for r in recs[1:])
     log(f"phase 1: {g.num_nodes} nodes; place() {t_place:.3f} s; set-up + "
         f"compile + iteration 1 {setup_s:.3f} s; steady s/iteration "
         f"{[float(s) for s in steady]} (mean {float(steady.mean())!r}); "
         f"in iterations 2..{FINETUNE_ITERS}: new jit programs "
-        f"{new_programs}, backend compiles "
-        f"{it_log.compiles[-1] - it_log.compiles[0]}")
+        f"{new_programs}, backend compiles {steady_compiles}")
     check(new_programs == 0, f"{new_programs} jit programs compiled in "
                              f"steady iterations")
-    check(it_log.compiles[-1] == it_log.compiles[0],
-          "backend compiles in steady iterations")
+    check(steady_compiles == 0, "backend compiles in steady iterations")
 
     # the plan against round-robin, judged by the same segmented env
     gb = featurize(g, topo=topo, scale=LARGE_SCALE.with_segment_padding())
@@ -294,18 +278,20 @@ def phase3_serving(tr):
 def main() -> None:
     dev = phase0_device()
     from repro.graphs import synthetic as S
-    counter = CompileCounter()
+    from repro.obs import jaxprof
+    compiles0 = jaxprof.backend_compiles()
     t0 = time.perf_counter()
     g = S.gnmt(8, time_steps=352)
     check(g.num_nodes >= 50_000, f"GNMT-8 has {g.num_nodes} nodes")
-    p1 = phase1_main_path(g, counter)
+    p1 = phase1_main_path(g)
     t1 = time.perf_counter()
     phase2_kernels(g, p1)
     t2 = time.perf_counter()
     phase3_serving(p1["tr"])
     t3 = time.perf_counter()
     log(f"phase seconds: main {t1 - t0:.3f}, kernels {t2 - t1:.3f}, "
-        f"serving {t3 - t2:.3f}; backend compiles {counter.n}")
+        f"serving {t3 - t2:.3f}; backend compiles "
+        f"{jaxprof.backend_compiles() - compiles0}")
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": len(jax.devices())}}), flush=True)

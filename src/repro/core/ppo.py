@@ -115,12 +115,19 @@ def _loss_fn(params, pcfg: PolicyConfig, gb: GraphBatch, num_devices: int,
 def _update_fn(params, opt_state, pcfg: PolicyConfig, ocfg: AdamConfig,
                gb: GraphBatch, num_devices: int, placements, old_logp, adv,
                clip_eps, entropy_coef, grad_clip):
-    (loss, aux), grads = jax.value_and_grad(_loss_fn, has_aux=True)(
-        params, pcfg, gb, num_devices, placements, old_logp, adv,
-        clip_eps, entropy_coef)
-    grads = sanitize(grads)
-    grads, gnorm = clip_by_global_norm(grads, grad_clip)
-    params, opt_state = adam_update(grads, opt_state, params, ocfg)
+    """One PPO step: gradient of the clipped surrogate, then sanitize, clip
+    and Adam.  The spans ``ppo.update.grad`` and ``ppo.update.optim`` time
+    host dispatch on the eager (segmented) path; where the whole function
+    is jitted they open at trace time only."""
+    tracer = get_tracer()
+    with tracer.span("ppo.update.grad", cat="ppo"):
+        (loss, aux), grads = jax.value_and_grad(_loss_fn, has_aux=True)(
+            params, pcfg, gb, num_devices, placements, old_logp, adv,
+            clip_eps, entropy_coef)
+    with tracer.span("ppo.update.optim", cat="ppo"):
+        grads = sanitize(grads)
+        grads, gnorm = clip_by_global_norm(grads, grad_clip)
+        params, opt_state = adam_update(grads, opt_state, params, ocfg)
     aux = dict(aux, loss=loss, gnorm=gnorm)
     return params, opt_state, aux
 
@@ -257,12 +264,13 @@ class PPOTrainer:
 
         The returned record carries the training-health telemetry
         (clip fraction, approx-KL, feasible-sample rate, wall time, jit
-        retrace count for this iteration) alongside the reward numbers;
-        ``train``/``finetune`` stream these records to an attached
-        :class:`~repro.obs.metrics.RunLog`.
+        retrace count and backend compiles for this iteration) alongside
+        the reward numbers; ``train``/``finetune`` stream these records to
+        an attached :class:`~repro.obs.metrics.RunLog`.
         """
         tracer = get_tracer()
         mon = jaxprof.RetraceMonitor()
+        compiles0 = jaxprof.backend_compiles()
         t_start = time.perf_counter()
         with tracer.span("ppo.sample", cat="ppo", graph=name):
             placements, old_logp = _sample_any(self.state.params, self.pcfg,
@@ -270,13 +278,16 @@ class PPOTrainer:
                                                self._next_key(),
                                                self.ppo.num_samples)
             if self.ppo.canonicalize:
-                placements = jnp.asarray(
-                    canonical_relabel(np.asarray(placements), gb.num_nodes))
-                old_logp, _ = _logp_any(self.state.params, self.pcfg, gb,
-                                        num_devices, placements)
+                host = np.asarray(placements)
+                with tracer.span("ppo.relabel", cat="ppo"):
+                    host = canonical_relabel(host, gb.num_nodes)
+                placements = jnp.asarray(host)
+                with tracer.span("ppo.logp", cat="ppo"):
+                    old_logp, _ = _logp_any(self.state.params, self.pcfg, gb,
+                                            num_devices, placements)
         with tracer.span("ppo.simulate", cat="ppo", graph=name):
             makespans, rewards, valid = env.rewards(placements)
-        rewards_np = np.asarray(rewards)
+            rewards_np = np.asarray(rewards)
         if self.ppo.baseline == "loo" and rewards_np.size > 1:
             m = rewards_np.size
             adv = (rewards_np - rewards_np.mean()) * m / (m - 1)
@@ -318,7 +329,8 @@ class PPOTrainer:
                 "clip_frac": float(aux.get("clip_frac", 0.0)),
                 "approx_kl": float(aux.get("approx_kl", 0.0)),
                 "iter_s": time.perf_counter() - t_start,
-                "retraces": mon.total_delta()}
+                "retraces": mon.total_delta(),
+                "compiles": jaxprof.backend_compiles() - compiles0}
 
     # ------------------------------------------------------------------
     def train(self, tasks: List[Tuple[str, GraphBatch, Any, int]],

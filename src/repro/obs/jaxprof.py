@@ -16,8 +16,9 @@ fall back to 0-with-a-shrug when a jax version hides it, never crash).
 :class:`RetraceMonitor` snapshots the registry so tests and benchmarks
 can pin *deltas* ("0 new compiles across this warm replay") rather than
 absolute counts, which module-level jits shared across tests would make
-flaky.  :func:`export_gauges` mirrors the counts into a
-:class:`~repro.obs.metrics.MetricsRegistry` as
+flaky.  :func:`backend_compiles` counts every XLA backend compile in
+the process, registered or not.  :func:`export_gauges` mirrors the
+counts into a :class:`~repro.obs.metrics.MetricsRegistry` as
 ``jax_jit_cache_size{fn=...}`` gauges so they ship with every metrics
 snapshot.
 
@@ -139,6 +140,35 @@ class RetraceMonitor:
 
     def total_delta(self) -> int:
         return sum(self.delta().values())
+
+
+# --------------------------------------------------------- backend compiles
+# Registered jits only see their own caches; the segmented paths compile
+# per-segment programs and eager ops the registry never hears of.  XLA
+# reports every backend compile through jax.monitoring, so one listener,
+# registered on first use, counts them all for the whole process.
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compiles = 0
+_listening = False
+
+
+def _on_duration_event(event: str, duration: float, **kwargs: Any) -> None:
+    global _compiles
+    if event == BACKEND_COMPILE_EVENT:
+        _compiles += 1
+
+
+def backend_compiles() -> int:
+    """XLA backend compiles in this process (every program, eager ops and
+    per-segment programs included) since the first call, which registers
+    the ``jax.monitoring`` listener; diff two readings for a region."""
+    global _listening
+    if not _listening:
+        import jax
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_duration_event)
+        _listening = True
+    return _compiles
 
 
 def export_gauges(registry) -> Dict[str, int]:

@@ -240,12 +240,31 @@ class Request:
     source: str = "pending"    # cache | disk | zero_shot | baseline | shed
     entry_source: str = ""     # provenance of the cache line that served it
     rejection: Optional[Rejection] = None   # set on typed oversize sheds
+    # batched zero-shot path only: added to the batcher, its flush began,
+    # its placements reached the host (``phases`` splits the latency)
+    queued_t: Optional[float] = None
+    flushed_t: Optional[float] = None
+    decoded_t: Optional[float] = None
 
     @property
     def latency(self) -> float:
         """Response time (done - arrival); requires a resolved request."""
         assert self.done_t is not None, "request not resolved yet"
         return self.done_t - self.arrival_t
+
+    def phases(self) -> Dict[str, float]:
+        """A batched zero-shot answer's latency by phase, which sum to
+        :attr:`latency`: ``prepare`` (fingerprint, lookup, context),
+        ``batch_wait`` (queued until its flush began), ``policy`` (the
+        batched decode up to the host copy of its placements), ``select``
+        (simulate-select, after earlier members of the batch); {} for
+        every other answer."""
+        if self.decoded_t is None or self.done_t is None:
+            return {}
+        return {"prepare": self.queued_t - self.arrival_t,
+                "batch_wait": self.flushed_t - self.queued_t,
+                "policy": self.decoded_t - self.flushed_t,
+                "select": self.done_t - self.decoded_t}
 
 
 @dataclasses.dataclass
@@ -367,6 +386,10 @@ class PlacementService:
         self._lat_hist = self.metrics.histogram(
             "serve_latency_seconds",
             "request latency observed at resolve time", ("source",))
+        self._phase_hist = self.metrics.histogram(
+            "serve_phase_seconds",
+            "batched zero-shot latency by phase (Request.phases)",
+            ("phase",))
         self.tid = 0   # trace lane; the cluster assigns worker indices
         if self.store is not None:
             for key, se in self.store.items():
@@ -398,9 +421,19 @@ class PlacementService:
         """
         if arrival_t is not None and self.clock.simulated:
             self.clock.advance_to(arrival_t)
+        with get_tracer().span("serve.submit", cat="serve", clock=self.clock,
+                               tid=self.tid):
+            return self._submit(g, topo, fp_order, topo_fp)
+
+    def _submit(self, g, topo: Topology,
+                fp_order: Optional[Tuple[str, np.ndarray]],
+                topo_fp: Optional[str]) -> Request:
+        tracer = get_tracer()
         now = self.clock.now()
-        graph_fp, order = fp_order or FP.fingerprint_and_order(g)
-        key = (graph_fp, topo_fp or self._topo_fp(topo))
+        with tracer.span("serve.fingerprint", cat="serve", clock=self.clock,
+                         tid=self.tid):
+            graph_fp, order = fp_order or FP.fingerprint_and_order(g)
+            key = (graph_fp, topo_fp or self._topo_fp(topo))
         req = Request(self._next_id, g, topo, now, key, order)
         self._next_id += 1
 
@@ -415,8 +448,8 @@ class PlacementService:
                                        self.cfg.max_graph_nodes,
                                        g.num_nodes)
 
-        with get_tracer().span("serve.lookup", cat="serve",
-                               clock=self.clock, tid=self.tid):
+        with tracer.span("serve.lookup", cat="serve", clock=self.clock,
+                         tid=self.tid):
             entry = self.cache.get(key)
             if self.clock.simulated:
                 self.clock.advance(self.cfg.costs.lookup_s)
@@ -432,8 +465,8 @@ class PlacementService:
             return req
 
         if self.store is not None:             # disk rung: evicted / warm
-            with get_tracer().span("serve.store_lookup", cat="serve",
-                                   clock=self.clock, tid=self.tid):
+            with tracer.span("serve.store_lookup", cat="serve",
+                             clock=self.clock, tid=self.tid):
                 if self.clock.simulated:
                     self.clock.advance(self.cfg.costs.store_lookup_s)
                 se = self.store.lookup(key)
@@ -451,6 +484,7 @@ class PlacementService:
             return req
         deadline = (now + self.cfg.deadline_s
                     if math.isfinite(self.cfg.deadline_s) else math.inf)
+        req.queued_t = self.clock.now()
         self.batcher.add(
             MicroBatcher.group_key(key[1], ctx.num_devices, g.num_nodes),
             req, ctx.gb, now, deadline=deadline)
@@ -525,6 +559,13 @@ class PlacementService:
         ctx = self._ctx.get(key)
         if ctx is not None:
             return ctx
+        with get_tracer().span("serve.context", cat="serve",
+                               clock=self.clock, tid=self.tid):
+            return self._new_context(key, g, topo, order)
+
+    def _new_context(self, key, g, topo: Topology,
+                     order: np.ndarray) -> _GraphCtx:
+        tracer = get_tracer()
         # contexts are a warm-start side table (envs, featurized arrays,
         # baselines); bound them like the cache, sparing in-flight keys
         if len(self._ctx) >= 4 * self.cfg.cache_capacity:
@@ -556,20 +597,28 @@ class PlacementService:
             pad_n = bucket_size(g.num_nodes)
         seg = (self.pcfg.segment if self.pcfg.segment and
                pad_n % self.pcfg.segment == 0 else None)
-        sg = prepare_sim_graph(g, topo, max_deg=16, pad_to=pad_n, pad_k=16)
-        env_true = Env.from_config(sg, topo, self.cfg.sim, segment=seg)
-        env_shaped = Env.from_config(
-            sg, topo, dataclasses.replace(self.cfg.sim, shaped_reward=True),
-            segment=seg)
-        gb = featurize(g, max_deg=self.cfg.max_deg, pad_to=pad_n, topo=topo)
+        span = partial(tracer.span, cat="serve", clock=self.clock,
+                       tid=self.tid)
+        with span("serve.sim_graph"):
+            sg = prepare_sim_graph(g, topo, max_deg=16, pad_to=pad_n,
+                                   pad_k=16)
+            env_true = Env.from_config(sg, topo, self.cfg.sim, segment=seg)
+            env_shaped = Env.from_config(
+                sg, topo,
+                dataclasses.replace(self.cfg.sim, shaped_reward=True),
+                segment=seg)
+        with span("serve.featurize"):
+            gb = featurize(g, max_deg=self.cfg.max_deg, pad_to=pad_n,
+                           topo=topo)
         base_best, base_pl = np.inf, None
-        for fn in (B.human_expert, B.round_robin):
-            pl = fn(g, topo)
-            pl_pad = np.zeros(pad_n, np.int32)
-            pl_pad[:g.num_nodes] = pl
-            mk, _, ok = env_true.rewards(pl_pad[None])
-            if bool(ok[0]) and float(mk[0]) < base_best:
-                base_best, base_pl = float(mk[0]), pl.astype(np.int32)
+        with span("serve.baselines"):
+            for fn in (B.human_expert, B.round_robin):
+                pl = fn(g, topo)
+                pl_pad = np.zeros(pad_n, np.int32)
+                pl_pad[:g.num_nodes] = pl
+                mk, _, ok = env_true.rewards(pl_pad[None])
+                if bool(ok[0]) and float(mk[0]) < base_best:
+                    base_best, base_pl = float(mk[0]), pl.astype(np.int32)
         ctx = _GraphCtx(gb, env_true, env_shaped, nd, base_best, base_pl,
                         order)
         self._ctx[key] = ctx
@@ -584,6 +633,8 @@ class PlacementService:
         req.entry_source = entry_source or source
         self.counts[source] += 1
         self._lat_hist.observe(req.latency, source=source)
+        for phase, dt in req.phases().items():
+            self._phase_hist.observe(dt, phase=phase)
         self.completed.append(req)
 
     def _flush(self, flushes) -> None:
@@ -591,6 +642,7 @@ class PlacementService:
             with get_tracer().span("serve.batch", cat="serve",
                                    clock=self.clock, tid=self.tid,
                                    real=fl.real):
+                flushed_t = self.clock.now()
                 if self.clock.simulated:
                     self.clock.advance(
                         self.cfg.costs.batch_base_s +
@@ -606,7 +658,9 @@ class PlacementService:
                     self._split(), self.cfg.num_samples,
                     self.cfg.temperature)
                 placements = np.asarray(placements, np.int32)  # [B, M, Npad]
+                decoded_t = self.clock.now()
             for i, req in enumerate(fl.items):
+                req.flushed_t, req.decoded_t = flushed_t, decoded_t
                 self._serve_zero_shot(req, placements[i])
 
     def _serve_zero_shot(self, req: Request, sampled: np.ndarray) -> None:
@@ -618,7 +672,7 @@ class PlacementService:
         with get_tracer().span("serve.zero_shot", cat="serve",
                                clock=self.clock, tid=self.tid):
             mks, _, valid = ctx.env_true.rewards(sampled[:, :pad_n])
-        mks = np.where(np.asarray(valid), np.asarray(mks), np.inf)
+            mks = np.where(np.asarray(valid), np.asarray(mks), np.inf)
         best = int(mks.argmin())
         pl, mk, source = sampled[best, :n], float(mks[best]), "zero_shot"
         if not np.isfinite(mk) and ctx.baseline_pl is not None:

@@ -30,7 +30,6 @@ import numpy as np
 
 from repro.core.graph import DataflowGraph
 from repro.obs import jaxprof
-from repro.obs.trace import get_tracer
 from repro.sim.cost_model import node_compute_matrix
 from repro.sim.device import Topology
 
@@ -469,12 +468,10 @@ class Env:
         Routes through a stable jitted wrapper so repeated calls with the
         same shapes and modes hit the pjit cache instead of re-tracing."""
         st = self.sim_topology
-        with get_tracer().span("sim.rewards", cat="sim",
-                               num_nodes=int(self.sg.compute_t.shape[0])):
-            return _simulate_batch_jit(self.sg, jnp.asarray(placements),
-                                       st.inv_bw, st.latency, st.mem_caps,
-                                       st.num_devices, self.shaped_reward,
-                                       self.sender_contention, self.segment,
-                                       self.receiver_contention,
-                                       self.jittered_bandwidth,
-                                       self.jitter_amp, self.jitter_seed)
+        return _simulate_batch_jit(self.sg, jnp.asarray(placements),
+                                   st.inv_bw, st.latency, st.mem_caps,
+                                   st.num_devices, self.shaped_reward,
+                                   self.sender_contention, self.segment,
+                                   self.receiver_contention,
+                                   self.jittered_bandwidth,
+                                   self.jitter_amp, self.jitter_seed)

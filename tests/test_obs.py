@@ -224,3 +224,59 @@ def test_peak_rss_gauge_is_positive():
     reg = MetricsRegistry()
     jaxprof.export_rss_gauge(reg)
     assert counters_flat(reg.snapshot())["process_peak_rss_bytes"] > 0
+
+
+def test_backend_compiles_count_every_program_once():
+    c0 = jaxprof.backend_compiles()
+    f = jax.jit(lambda x: x * 3 - 1)          # registered nowhere
+    f(np.ones(7, np.float32))
+    c1 = jaxprof.backend_compiles()
+    assert c1 >= c0 + 1
+    f(np.ones(7, np.float32))                 # warm: nothing compiles
+    assert jaxprof.backend_compiles() == c1
+    f(np.ones(9, np.float32))                 # new shape: one more
+    assert jaxprof.backend_compiles() == c1 + 1
+
+
+def test_spans_share_the_profiler_clock():
+    """A served batch's device program, moved onto the host clock by the
+    benchmark's trace reduction (through its window annotation), lies
+    inside the ``serve.batch`` span that dispatched and awaited it."""
+    import os
+    import sys
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from chipbench import trace as ctrace
+    from repro.core.policy import PolicyConfig
+    from repro.core.ppo import PPOConfig, PPOTrainer
+    from repro.graphs import synthetic as S
+    from repro.serve import PlacementService, ServeConfig, WallClock
+    from repro.sim import p100_topology
+
+    trainer = PPOTrainer(PolicyConfig(hidden=32, gnn_layers=2,
+                                      placer_layers=1, ffn=64, window=32,
+                                      max_devices=8),
+                         PPOConfig(num_samples=2, epochs=1), seed=0)
+    svc = PlacementService(trainer, ServeConfig(max_batch=1, num_samples=2,
+                                                finetune_iters=0),
+                           clock=WallClock())
+    topo = p100_topology(4)
+    svc.submit(S.rnnlm(2, time_steps=3), topo)        # compiles the bucket
+    mine = Tracer()
+    old = set_tracer(mine)
+    try:
+        with ctrace.Capture() as cap:                 # a full batch flushes
+            req = svc.submit(S.rnnlm(2, time_steps=4), topo)
+    finally:
+        set_tracer(old)
+    assert req.source == "zero_shot"
+    lo = [s for s, _, n in cap.raw["host"] if n == ctrace.WINDOW][0]
+    shift = lo - cap.clock_start
+    batch = [s for s in mine.spans if s.name == "serve.batch"]
+    assert len(batch) == 1
+    b0, b1 = batch[0].ts + shift, batch[0].ts + batch[0].dur + shift
+    progs = [(s, e) for ivs in cap.raw["devices"].values()
+             for s, e, n in ivs if "sample_batch" in n]
+    assert progs
+    for s, e in progs:
+        assert b0 - 1e-3 <= s and e <= b1 + 1e-3, (s - b0, e - b1)

@@ -69,6 +69,55 @@ def test_ppo_zero_recompiles_after_first_iteration():
     for _ in range(3):
         m = tr.iteration("t", gb, FracEnv(), 4)
         assert m["retraces"] == 0                 # per-iteration metric
+        assert m["compiles"] == 0                 # every backend compile
         assert m["iter_s"] > 0
         assert np.isfinite(m["clip_frac"]) and np.isfinite(m["approx_kl"])
     assert mon.total_delta() == 0                 # zero new programs total
+
+
+def test_iteration_spans_split_sampling_and_update():
+    """A segmented PPO iteration nests the numpy relabel and the re-score
+    in ``ppo.sample`` and the gradient and optimizer dispatch in
+    ``ppo.update``; the simulator opens no span of its own."""
+    from repro.core.scale import ScaleConfig
+    from repro.obs.trace import Tracer, set_tracer
+    from repro.sim.scheduler import Env, SimConfig, prepare_sim_graph
+
+    seg = 16
+    g = S.rnnlm(2, time_steps=3)
+    topo = p100_topology(4).with_mem_caps(g.total_mem() / 4 * 1.8)
+    gb = featurize(g, max_deg=8, topo=topo,
+                   scale=ScaleConfig(pad_multiple=seg))
+    env = Env.from_config(prepare_sim_graph(g, topo, pad_multiple=seg),
+                          topo, SimConfig(shaped_reward=True), segment=seg)
+    pcfg = PolicyConfig(hidden=32, gnn_layers=2, placer_layers=1, ffn=64,
+                        window=32, max_devices=8,
+                        scale=ScaleConfig(segment=seg, gnn_chunk=seg))
+    tr = PPOTrainer(pcfg, PPOConfig(num_samples=4, epochs=1), seed=0)
+    mine = Tracer()
+    old = set_tracer(mine)
+    try:
+        m = tr.iteration("t", gb, env, 4)
+    finally:
+        set_tracer(old)
+    assert isinstance(m["compiles"], int) and m["compiles"] >= 0
+    by = {}
+    for s in mine.spans:
+        by.setdefault(s.name, []).append(s)
+    assert "sim.rewards" not in by
+
+    def inside(child, parent):
+        return (parent.ts <= child.ts and
+                child.ts + child.dur <= parent.ts + parent.dur)
+
+    (sample,), (update,), (sim,) = (by["ppo.sample"], by["ppo.update"],
+                                    by["ppo.simulate"])
+    for name in ("ppo.relabel", "ppo.logp"):
+        (child,) = by[name]
+        assert inside(child, sample), name
+    for name in ("ppo.update.grad", "ppo.update.optim"):
+        (child,) = by[name]
+        assert inside(child, update), name
+    assert by["ppo.relabel"][0].ts < by["ppo.logp"][0].ts
+    assert by["ppo.update.grad"][0].ts < by["ppo.update.optim"][0].ts
+    assert sample.ts + sample.dur <= sim.ts <= update.ts

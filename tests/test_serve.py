@@ -416,3 +416,120 @@ def test_one_compile_per_bucket_on_warm_replay():
         assert ra.source == "zero_shot" and rb.source == "zero_shot"
     assert svc.counts["zero_shot"] >= 22          # replay ran real inference
     assert mon.delta() == {}                      # zero new compiles anywhere
+
+
+# ------------------------------------------------------ miss-path tracing
+def _inside(child, parent):
+    return (parent.ts <= child.ts and
+            child.ts + child.dur <= parent.ts + parent.dur)
+
+
+def test_miss_path_spans_nest_under_submit():
+    """On the wall clock a miss records ``serve.submit`` holding
+    ``serve.fingerprint`` and ``serve.context``, which holds the simulator
+    graph, featurize and baseline spans; the two children cover most of
+    the submit."""
+    from repro.obs.trace import Tracer, set_tracer
+    from repro.serve import WallClock
+    svc = PlacementService(_small_trainer(), ServeConfig(
+        max_batch=2, num_samples=2, finetune_iters=0), clock=WallClock())
+    g = S.rnnlm(2, time_steps=3)
+    topo = p100_topology(4)
+    svc.submit(g, topo)                     # compiles the bucket's programs
+    svc.step(force=True)
+    mine = Tracer()
+    old = set_tracer(mine)
+    try:
+        req = svc.submit(_flops_scaled(g, 1.5), topo)
+    finally:
+        set_tracer(old)
+    svc.step(force=True)
+    assert req.source == "zero_shot"
+    by = {}
+    for s in mine.spans:
+        by.setdefault(s.name, []).append(s)
+    (sub,), (fp,), (ctx,) = (by["serve.submit"], by["serve.fingerprint"],
+                             by["serve.context"])
+    assert _inside(fp, sub) and _inside(ctx, sub)
+    for name in ("serve.sim_graph", "serve.featurize", "serve.baselines"):
+        (child,) = by[name]
+        assert _inside(child, ctx), name
+    assert fp.dur + ctx.dur >= 0.8 * sub.dur, (fp.dur, ctx.dur, sub.dur)
+
+
+def test_zero_shot_phases_sum_to_latency():
+    """Each batched zero-shot answer splits its latency into prepare,
+    batch wait, policy and select, observed into ``serve_phase_seconds``;
+    cache hits observe no phase."""
+    from repro.serve import WallClock
+    svc = PlacementService(_small_trainer(), ServeConfig(
+        max_batch=2, num_samples=2, finetune_iters=0), clock=WallClock())
+    g = S.rnnlm(2, time_steps=3)
+    topo = p100_topology(4)
+    reqs = [svc.submit(_flops_scaled(g, 1.0 + 0.1 * i), topo)
+            for i in range(3)]
+    svc.step(force=True)
+    hit = svc.submit(g, topo)               # the first one, now cached
+    assert hit.source == "cache" and hit.phases() == {}
+    for r in reqs:
+        assert r.source == "zero_shot"
+        ph = r.phases()
+        assert set(ph) == {"prepare", "batch_wait", "policy", "select"}
+        assert all(v >= 0 for v in ph.values()), ph
+        assert abs(sum(ph.values()) - r.latency) <= 1e-9
+    h = svc.metrics.histogram("serve_phase_seconds", "", ("phase",))
+    for phase in ("prepare", "batch_wait", "policy", "select"):
+        assert h.count({"phase": phase}) == len(reqs)
+    assert "serve_phase_seconds" in svc.snapshot()
+    assert 'serve_phase_seconds_count{phase="batch_wait"} 3' in \
+        svc.metrics.to_prometheus()
+
+
+def test_phases_follow_the_simulated_cost_model():
+    """On the simulated clock each phase is the cost model's charge: the
+    lookup before the batcher, no wait for a full batch of one, one
+    batched call, and a selection that costs no simulated time."""
+    cfg = ServeConfig(max_batch=1, num_samples=2, simulated=True,
+                      finetune_iters=0)
+    svc = PlacementService(_small_trainer(), cfg, SimulatedClock())
+    r = svc.submit(S.rnnlm(2, time_steps=3), p100_topology(4),
+                   arrival_t=2.0)
+    c = cfg.costs
+    assert r.phases() == pytest.approx({
+        "prepare": c.lookup_s, "batch_wait": 0.0,
+        "policy": c.batch_base_s + c.batch_per_graph_s, "select": 0.0})
+
+
+def test_tracing_changes_no_answer():
+    """The same replay with the tracer on and off gives the same
+    placements, makespans and (simulated) latencies."""
+    from repro.obs.trace import Tracer, set_tracer
+    g = S.rnnlm(2, time_steps=3)
+    trace = [g, _flops_scaled(g, 1.3), g, _relabeled(g, 1),
+             _flops_scaled(g, 1.7)]
+    topo = p100_topology(4)
+    trainer = _small_trainer()
+
+    def replay(enabled):
+        mine = Tracer(enabled=enabled)
+        old = set_tracer(mine)
+        try:
+            svc = PlacementService(trainer, ServeConfig(
+                max_batch=2, num_samples=2, simulated=True,
+                finetune_iters=0, seed=3), SimulatedClock())
+            reqs = []
+            for i, gi in enumerate(trace):
+                reqs.append(svc.submit(gi, topo, arrival_t=0.01 * i))
+                svc.step()
+            svc.drain()
+        finally:
+            set_tracer(old)
+        return reqs, mine.spans
+
+    on, spans = replay(True)
+    off, none = replay(False)
+    assert none == [] and any(s.name == "serve.submit" for s in spans)
+    for a, b in zip(on, off):
+        assert a.source == b.source
+        np.testing.assert_array_equal(a.placement, b.placement)
+        assert a.makespan == b.makespan and a.latency == b.latency
