@@ -356,11 +356,14 @@ def apply_tf_segmented(params: Dict[str, Any], h: jnp.ndarray,
     ``window - 1`` keys/values; only the dot kernels XLA picks for the
     smaller block shape round differently.
     Memory crossing a segment boundary is ``stop_gradient``-ed
-    (Transformer-XL recurrence): forward values are unchanged.  Each
-    segment is rematerialized in the backward pass (``jax.checkpoint``),
-    so a gradient holds one segment's activations at a time, not the
-    whole graph's — at the 50k-node GNMT-8 the saved [S, W, heads, hd]
-    band gathers alone would fill a 16 GB chip.
+    (Transformer-XL recurrence): forward values are unchanged.  The
+    segments run as one ``lax.scan`` over ``[nseg, segment, ...]`` views
+    of the padded inputs, carrying the per-layer memory, so under an
+    outer jit the whole pass is one program whose size does not grow with
+    the node count.  Each segment is rematerialized in the backward pass
+    (``jax.checkpoint``), so a gradient holds one segment's activations
+    at a time, not the whole graph's — at the 50k-node GNMT-8 the saved
+    [S, W, heads, hd] band gathers alone would fill a 16 GB chip.
     """
     n, hid = h.shape
     pad = (-n) % segment
@@ -379,27 +382,33 @@ def apply_tf_segmented(params: Dict[str, Any], h: jnp.ndarray,
     cap = _cap_vector(params, dev_mem_cap) if mask_full else None
     nlayers = len(params["layers"])
     hd = hid // heads
+    nseg = (n + pad) // segment
     kmem = jnp.zeros((nlayers, window - 1, heads, hd))
     vmem = jnp.zeros((nlayers, window - 1, heads, hd))
-    outs = []
-    tracer = get_tracer()
+    # the scan already keeps the forward and the rematerialized backward
+    # in separate loop bodies, so CSE barriers would only cost fusion
     step = jax.checkpoint(partial(
         _tf_segment, heads=heads, num_devices=num_devices,
-        use_attention=use_attention, attn_impl=attn_impl))
-    for s0 in range(0, n + pad, segment):
-        sl = slice(s0, s0 + segment)
-        # per-segment spans time the eager orchestration of the compiled
-        # step (first segment of a fresh shape carries the trace/compile)
-        with tracer.span("placer.tf_segment", cat="placer", seg_start=s0,
-                         segment=segment):
-            logits, kmem, vmem = step(
-                params, x[sl], jax.lax.stop_gradient(kmem),
-                jax.lax.stop_gradient(vmem), node_mask[sl],
-                jnp.int32(s0), c, dev_keys, mem_before[sl], mem_frac[sl],
-                cap,
-                None if incumbent_bias is None else incumbent_bias[sl])
-        outs.append(logits)
-    return jnp.concatenate(outs)[:n]
+        use_attention=use_attention, attn_impl=attn_impl),
+        prevent_cse=False)
+
+    def body(carry, xs):
+        kmem, vmem = carry
+        x_s, mask_s, mb_s, mf_s, bias_s, base = xs
+        logits, kmem, vmem = step(
+            params, x_s, jax.lax.stop_gradient(kmem),
+            jax.lax.stop_gradient(vmem), mask_s, base, c, dev_keys, mb_s,
+            mf_s, cap, bias_s)
+        return (kmem, vmem), logits
+
+    def segs(a):
+        return None if a is None else a.reshape(nseg, segment, *a.shape[1:])
+
+    _, logits = jax.lax.scan(
+        body, (kmem, vmem),
+        (segs(x), segs(node_mask), segs(mem_before), segs(mem_frac),
+         segs(incumbent_bias), jnp.arange(nseg, dtype=jnp.int32) * segment))
+    return logits.reshape(n + pad, -1)[:n]
 
 
 # ------------------------------------------------------------- AR sampling
@@ -530,7 +539,9 @@ def _ar_segment_scan(params, h_seg, idx_seg, keys_seg, mf_seg, cf_seg,
 
 
 # "one program per segment config": every segment of every graph must hit
-# these two caches — their counts are exported as gauges and pinned
+# these two caches — their counts are exported as gauges and pinned.  The
+# TF pass traces ``_tf_segment`` as its scan body, so that cache grows only
+# when a segment is dispatched on its own
 jaxprof.register("placer.tf_segment", _tf_segment)
 jaxprof.register("placer.ar_segment_scan", _ar_segment_scan)
 

@@ -112,28 +112,41 @@ def _loss_fn(params, pcfg: PolicyConfig, gb: GraphBatch, num_devices: int,
                   "clip_frac": clip_frac, "approx_kl": approx_kl}
 
 
+@partial(jax.jit, static_argnames=("pcfg", "num_devices"))
+def _update_grad(params, pcfg: PolicyConfig, gb: GraphBatch,
+                 num_devices: int, placements, old_logp, adv, clip_eps,
+                 entropy_coef):
+    """Loss, telemetry and gradient of the clipped surrogate."""
+    return jax.value_and_grad(_loss_fn, has_aux=True)(
+        params, pcfg, gb, num_devices, placements, old_logp, adv, clip_eps,
+        entropy_coef)
+
+
+@partial(jax.jit, static_argnames=("ocfg",))
+def _update_optim(grads, opt_state, params, ocfg: AdamConfig, grad_clip):
+    """Sanitize, clip and Adam: (params, opt_state, pre-clip norm)."""
+    grads = sanitize(grads)
+    grads, gnorm = clip_by_global_norm(grads, grad_clip)
+    params, opt_state = adam_update(grads, opt_state, params, ocfg)
+    return params, opt_state, gnorm
+
+
 def _update_fn(params, opt_state, pcfg: PolicyConfig, ocfg: AdamConfig,
                gb: GraphBatch, num_devices: int, placements, old_logp, adv,
                clip_eps, entropy_coef, grad_clip):
-    """One PPO step: gradient of the clipped surrogate, then sanitize, clip
-    and Adam.  The spans ``ppo.update.grad`` and ``ppo.update.optim`` time
-    host dispatch on the eager (segmented) path; where the whole function
-    is jitted they open at trace time only."""
+    """One PPO step as two compiled programs: the gradient of the clipped
+    surrogate, then sanitize, clip and Adam.  The spans ``ppo.update.grad``
+    and ``ppo.update.optim`` time the dispatch of one program each."""
     tracer = get_tracer()
     with tracer.span("ppo.update.grad", cat="ppo"):
-        (loss, aux), grads = jax.value_and_grad(_loss_fn, has_aux=True)(
+        (loss, aux), grads = _update_grad(
             params, pcfg, gb, num_devices, placements, old_logp, adv,
             clip_eps, entropy_coef)
     with tracer.span("ppo.update.optim", cat="ppo"):
-        grads = sanitize(grads)
-        grads, gnorm = clip_by_global_norm(grads, grad_clip)
-        params, opt_state = adam_update(grads, opt_state, params, ocfg)
+        params, opt_state, gnorm = _update_optim(grads, opt_state, params,
+                                                 ocfg, grad_clip)
     aux = dict(aux, loss=loss, gnorm=gnorm)
     return params, opt_state, aux
-
-
-_update = partial(jax.jit, static_argnames=("pcfg", "num_devices", "ocfg")
-                  )(_update_fn)
 
 
 @partial(jax.jit, static_argnames=("pcfg", "num_devices", "num_samples"))
@@ -151,15 +164,17 @@ def _logp(params, pcfg: PolicyConfig, gb: GraphBatch, num_devices: int,
 
 # "one program per (bucket, D) config" — iterations 2..N must reuse the
 # programs traced in iteration 1; tests pin these registrations' deltas
-jaxprof.register("ppo.update", _update)
+jaxprof.register("ppo.update", _update_grad)
+jaxprof.register("ppo.update.optim", _update_optim)
 jaxprof.register("ppo.sample", _sample)
 jaxprof.register("ppo.logp", _logp)
 
 
-# Segmented configs manage their own per-segment compiled programs: an
-# outer jit would trace the Python segment loop into one giant graph-sized
-# XLA program — exactly the compile blow-up segmenting exists to avoid —
-# so these dispatchers route them to the eager orchestrators instead.
+# Every config re-scores and updates through the jitted programs: the
+# segmented TF pass is a ``lax.scan`` over segments, so its program does
+# not grow with the graph.  Segmented sampling stays eager: the AR decode
+# is a Python loop over per-segment compiled scans, and an outer jit would
+# trace that loop into one graph-sized XLA program.
 def _sample_any(params, pcfg, gb, num_devices, key, num_samples):
     if pcfg.segment is None:
         return _sample(params, pcfg, gb, num_devices, key, num_samples)
@@ -167,17 +182,15 @@ def _sample_any(params, pcfg, gb, num_devices, key, num_samples):
 
 
 def _logp_any(params, pcfg, gb, num_devices, placements):
-    if pcfg.segment is None:
-        return _logp(params, pcfg, gb, num_devices, placements)
-    return policy_mod.logp_and_entropy(params, pcfg, gb, num_devices,
-                                       placements)
+    return _logp(params, pcfg, gb, num_devices, placements)
 
 
 def _update_any(params, opt_state, pcfg, ocfg, gb, num_devices, placements,
                 old_logp, adv, clip_eps, entropy_coef, grad_clip):
-    fn = _update if pcfg.segment is None else _update_fn
-    return fn(params, opt_state, pcfg, ocfg, gb, num_devices, placements,
-              old_logp, adv, clip_eps, entropy_coef, grad_clip)
+    # through the module global, so a patched ``_update_fn`` is the one run
+    return _update_fn(params, opt_state, pcfg, ocfg, gb, num_devices,
+                      placements, old_logp, adv, clip_eps, entropy_coef,
+                      grad_clip)
 
 
 def canonical_relabel(placements: np.ndarray, num_nodes: int) -> np.ndarray:
@@ -311,7 +324,7 @@ class PPOTrainer:
                                         self.state.opt_state,
                                         self.pcfg, self.ocfg, gb,
                                         num_devices, placements, old_logp,
-                                        jnp.asarray(adv),
+                                        adv,
                                         self.ppo.clip_eps, ent_coef,
                                         self.ppo.grad_clip)
                 self.state.params, self.state.opt_state = p, o

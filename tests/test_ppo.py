@@ -121,3 +121,56 @@ def test_iteration_spans_split_sampling_and_update():
     assert by["ppo.relabel"][0].ts < by["ppo.logp"][0].ts
     assert by["ppo.update.grad"][0].ts < by["ppo.update.optim"][0].ts
     assert sample.ts + sample.dur <= sim.ts <= update.ts
+
+
+def test_warm_segmented_iteration_dispatches_compiled_programs(monkeypatch):
+    """A warm segmented PPO iteration re-scores and updates through the
+    compiled programs: the Python body of ``_tf_segment`` is entered only
+    while iteration 1 traces them, iteration 2 adds no program and no
+    backend compile, and each epoch opens one ``ppo.update.grad`` and one
+    ``ppo.update.optim`` inside ``ppo.update``."""
+    from repro.core import placer as PL
+    from repro.core.scale import ScaleConfig
+    from repro.obs.trace import Tracer, set_tracer
+    from repro.sim.scheduler import Env, SimConfig, prepare_sim_graph
+
+    entered = []
+    real = PL._tf_segment
+
+    def counting(*a, **k):
+        entered.append(1)
+        return real(*a, **k)
+    monkeypatch.setattr(PL, "_tf_segment", counting)
+
+    seg = 16
+    g = S.rnnlm(2, time_steps=3)
+    topo = p100_topology(4).with_mem_caps(g.total_mem() / 4 * 1.8)
+    gb = featurize(g, max_deg=8, topo=topo,
+                   scale=ScaleConfig(pad_multiple=seg))
+    env = Env.from_config(prepare_sim_graph(g, topo, pad_multiple=seg),
+                          topo, SimConfig(shaped_reward=True), segment=seg)
+    # a width no other test uses, so iteration 1 traces here
+    pcfg = PolicyConfig(hidden=24, gnn_layers=1, placer_layers=1, ffn=48,
+                        window=16, max_devices=8,
+                        scale=ScaleConfig(segment=seg, gnn_chunk=seg))
+    tr = PPOTrainer(pcfg, PPOConfig(num_samples=4, epochs=1), seed=0)
+    tr.iteration("t", gb, env, 4)
+    assert entered                                # traced in iteration 1
+    entered.clear()
+    mine = Tracer()
+    old = set_tracer(mine)
+    try:
+        m = tr.iteration("t", gb, env, 4)
+    finally:
+        set_tracer(old)
+    assert entered == []
+    assert m["retraces"] == 0 and m["compiles"] == 0
+    by = {}
+    for s in mine.spans:
+        by.setdefault(s.name, []).append(s)
+    assert "placer.tf_segment" not in by
+    (update,) = by["ppo.update"]
+    for name in ("ppo.update.grad", "ppo.update.optim"):
+        (child,) = by[name]
+        assert (update.ts <= child.ts and
+                child.ts + child.dur <= update.ts + update.dur), name

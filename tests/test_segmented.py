@@ -106,6 +106,119 @@ def test_tf_segmented_bitwise(setup, seg):
                                rtol=1e-6, atol=1e-6)
 
 
+def _tf_segment_loop(params, h, node_mask, placements, c, mem_frac,
+                     comp_frac, dev_feats, *, segment, window, heads,
+                     num_devices, attn_impl, cap=None, bias=None):
+    """The segmented TF pass as a Python loop that dispatches the
+    checkpointed ``_tf_segment`` once per segment (the scan's oracle)."""
+    n, hid = h.shape
+    pad = (-n) % segment
+    h = jnp.pad(h, ((0, pad), (0, 0)))
+    node_mask, placements, mem_frac, comp_frac = (
+        jnp.pad(a, (0, pad)) for a in (node_mask, placements, mem_frac,
+                                       comp_frac))
+    if bias is not None:
+        bias = jnp.pad(bias, ((0, pad), (0, 0)))
+    prev, ctx, mem_before = PL._tf_ctx(params, placements, node_mask,
+                                       mem_frac, comp_frac)
+    x = PL._inputs(params, h, prev, ctx)
+    dev_keys = PL._dev_keys(params, dev_feats)
+    mem = jnp.zeros((len(params["layers"]), window - 1, heads, hid // heads))
+    kmem = vmem = mem
+    step = jax.checkpoint(partial(PL._tf_segment, heads=heads,
+                                  num_devices=num_devices,
+                                  use_attention=True, attn_impl=attn_impl))
+    outs = []
+    for s0 in range(0, n + pad, segment):
+        sl = slice(s0, s0 + segment)
+        logits, kmem, vmem = step(
+            params, x[sl], jax.lax.stop_gradient(kmem),
+            jax.lax.stop_gradient(vmem), node_mask[sl], jnp.int32(s0), c,
+            dev_keys, mem_before[sl], mem_frac[sl], cap,
+            None if bias is None else bias[sl])
+        outs.append(logits)
+    return jnp.concatenate(outs)[:n]
+
+
+@pytest.mark.parametrize("attn_impl,masked", [("jnp", False), ("jnp", True),
+                                               ("pallas_band", False)])
+def test_tf_segmented_scan_matches_segment_loop(setup, attn_impl, masked):
+    """The scanned segmented TF pass equals a per-segment loop over
+    ``_tf_segment``: float32 logits to 1e-6, and the gradient of a scalar
+    loss through either to 1e-5 relative on every leaf.  The gradients are
+    taken in float64, where the summation order the scan changes no
+    longer shows, so what is compared is the algorithm: the carried
+    memory, its stop-gradient at each segment boundary, and each
+    segment's rematerialized backward."""
+    g, gb, params = setup
+    h = gnn.apply(params["gnn"], gb)
+    from repro.core import superposition
+    c = superposition.gain(params["sp"],
+                           gnn.graph_summary(h, gb.node_mask))
+    pl, _ = P.sample(params, CFG, gb, 4, jax.random.PRNGKey(5), 1)
+    pl = pl[0]
+    # the init scales the attention output by 1e-2; undo that so the band,
+    # the carried memory and their gradients weigh in the logits
+    placer = dict(params["placer"], layers=[
+        dict(lp, wo=dict(lp["wo"], w=100.0 * lp["wo"]["w"]))
+        for lp in params["placer"]["layers"]])
+    caps = jnp.full((4,), 0.4) if masked else None
+    bias = (0.5 * gb.mem_frac[:, None] * jax.nn.one_hot((pl + 1) % 4,
+                                                        CFG.max_devices)
+            if masked else None)
+    kw = dict(segment=SEG, window=CFG.window, heads=CFG.heads,
+              num_devices=4, attn_impl=attn_impl)
+
+    def passes(dtype):
+        def cast(a):
+            return None if a is None else jnp.asarray(a, dtype)
+        args = [cast(a) for a in (h, gb.node_mask)] + [pl] + [
+            cast(a) for a in (c, gb.mem_frac, gb.comp_frac, gb.dev_feats)]
+        b = cast(bias)
+
+        def scanned(pp):
+            return PL.apply_tf_segmented(
+                pp, *args, dev_mem_cap=cast(caps), mask_full=masked,
+                incumbent_bias=b, **kw)
+
+        def looped(pp):
+            cap = PL._cap_vector(pp, cast(caps)) if masked else None
+            return _tf_segment_loop(pp, *args, cap=cap, bias=b, **kw)
+
+        return (jax.tree_util.tree_map(cast, placer), scanned, looped)
+
+    pp, scanned, looped = passes(jnp.float32)
+    np.testing.assert_allclose(np.asarray(scanned(pp)),
+                               np.asarray(looped(pp)), rtol=1e-6, atol=1e-6)
+
+    def loss(tf):
+        def f(pp):
+            lp = jax.nn.log_softmax(tf(pp), axis=-1)
+            node_lp = jnp.take_along_axis(lp, pl[:, None], axis=-1)[:, 0]
+            return (node_lp * gb.node_mask).sum() + (jnp.exp(lp) * lp).sum()
+        return f
+
+    with jax.enable_x64(True):
+        pp, scanned, looped = passes(jnp.float64)
+        flat_s = jax.tree_util.tree_leaves_with_path(
+            jax.grad(loss(scanned))(pp))
+        flat_l = [np.asarray(a) for a in
+                  jax.tree_util.tree_leaves(jax.grad(loss(looped))(pp))]
+    assert len(flat_s) == len(flat_l)
+    assert all(np.asarray(a).dtype == np.float64 for _, a in flat_s)
+    # a leaf whose gradient is nought by symmetry (a key or device-key
+    # bias under the softmax) reads only rounding: nought in both
+    tiny = 1e-9 * float(np.median([np.linalg.norm(b) for b in flat_l]))
+    for (path, a), b in zip(flat_s, flat_l):
+        name = jax.tree_util.keystr(path)
+        a = np.asarray(a)
+        if np.linalg.norm(b) < tiny:
+            assert np.linalg.norm(a) < tiny, name
+            continue
+        gap = np.linalg.norm(a - b) / np.linalg.norm(b)
+        assert gap <= 1e-5, (name, gap)
+
+
 def test_logp_segmented_matches_monolithic(setup):
     """Policy-level PPO ratio path: per-node logp from the segmented TF
     pass equals the monolithic one to float tolerance on real nodes."""
@@ -197,9 +310,10 @@ def test_env_segment_threading(contention):
 
 # ----------------------------------------------------- segmented PPO run
 def test_segmented_ppo_iteration_runs():
-    """A segment-native PPO fine-tune iteration (eager orchestration,
-    per-segment compiled programs) trains end-to-end on a segment-padded
-    task and produces finite, valid makespans."""
+    """A segment-native PPO fine-tune iteration (eager segmented decode,
+    scanned TF pass inside the jitted re-score and update) trains
+    end-to-end on a segment-padded task and produces finite, valid
+    makespans."""
     from benchmarks import common as C
     pcfg = dataclasses.replace(CFG, segment=SEG, gnn_chunk=SEG)
     ppo = PPOConfig(num_samples=4, epochs=1)
