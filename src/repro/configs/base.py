@@ -9,8 +9,9 @@ Layer patterns: a model is ``scan`` over ``n_layers/period`` groups; each
 group applies ``period`` layer descriptors.  Descriptors say which mixer
 (attention variant / SSM) and which FFN (dense / MoE) a layer uses — this
 single mechanism expresses dense stacks, gemma's local/global alternation,
-deepseek/arctic MoE, xLSTM's mLSTM/sLSTM alternation and jamba's 1:7
-attention:mamba interleave.
+deepseek/arctic MoE, xLSTM's mLSTM/sLSTM alternation, jamba's 1:7
+attention:mamba interleave and Nemotron-H's layers of one sublayer each
+(Mamba-2, MoE or attention alone), written out as one group.
 """
 from __future__ import annotations
 
@@ -23,6 +24,7 @@ MIXER_ATTN_LOCAL = "attn_local"
 MIXER_MAMBA = "mamba"          # SSD-style selective SSM
 MIXER_MLSTM = "mlstm"
 MIXER_SLSTM = "slstm"
+MIXER_NONE = "none"            # nemotron-h: a layer holding its FFN alone
 
 FFN_DENSE = "dense"
 FFN_MOE = "moe"
@@ -44,6 +46,15 @@ class MoEConfig:
     d_expert: Optional[int] = None   # defaults to d_ff
     capacity_factor: float = 1.25
     router_dtype: str = "float32"
+    d_shared: Optional[int] = None   # shared experts' width; defaults to d_expert
+    activation: str = "swiglu"       # swiglu (gated) | relu2 (non-gated relu^2)
+    router: str = "softmax"          # softmax | sigmoid (scores, top-k by
+                                     # score + a selection-only bias)
+    routed_scale: float = 1.0        # scales the routed experts' weights
+
+    def expert_mats(self) -> int:
+        """Weight matrices per expert: up, down and, when gated, gate."""
+        return 3 if self.activation == "swiglu" else 2
 
 
 @dataclasses.dataclass(frozen=True)
@@ -74,6 +85,13 @@ class ArchConfig:
     # ssm dims
     ssm_state: int = 64
     ssm_heads: Optional[int] = None
+    ssm_head_dim: Optional[int] = None   # d_inner = heads x head dim; default 2·d_model
+    ssm_groups: int = 0                  # >0: Mamba-2's own layer, B and C per
+                                         # group (fused in-projection, conv over
+                                         # x, B, C, gated RMSNorm); 0: the SSD
+                                         # stand-in (one B, C; conv over x)
+    ssm_chunk: int = 256                 # SSD chunk length
+    norm_eps: float = 1e-6
     # numerics / memory policy
     param_dtype: str = "bfloat16"
     activ_dtype: str = "bfloat16"
@@ -99,6 +117,36 @@ class ArchConfig:
                                                   self.period)
         return self.n_layers // self.period
 
+    @property
+    def ssm_inner(self) -> int:
+        """Mamba's inner width."""
+        if self.ssm_head_dim:
+            return self.ssm_heads * self.ssm_head_dim
+        return 2 * self.d_model
+
+    @property
+    def ssm_nheads(self) -> int:
+        return self.ssm_heads or max(self.ssm_inner // 128, 1)
+
+    def first_layers(self, n: int) -> "ArchConfig":
+        """The same model cut to its first ``n`` layers, which must be whole
+        periods: whole groups, or, where the pattern is written out as one
+        group, a prefix that the pattern repeats and that holds every kind
+        of layer the model has."""
+        layers = self.pattern * self.n_groups
+        if n == self.n_layers:
+            return self
+        head = layers[:n]
+        whole = (n % self.period == 0 if self.n_groups > 1 else
+                 0 < n and layers[n:2 * n] == head and
+                 set(head) == set(layers))
+        if not whole:
+            raise ValueError(f"{self.name}: the first {n} layers are not "
+                             f"whole periods of its pattern")
+        if self.n_groups > 1:
+            return dataclasses.replace(self, n_layers=n)
+        return dataclasses.replace(self, n_layers=n, pattern=head)
+
     def has_mixer(self, kind: str) -> bool:
         return any(d.mixer == kind for d in self.pattern)
 
@@ -110,11 +158,14 @@ class ArchConfig:
         d, ff, hd = self.d_model, self.d_ff, self.hd
         qkv = d * hd * (self.n_heads + 2 * self.n_kv) + self.n_heads * hd * d
         dense_ffn = 3 * d * ff
-        total = self.vocab * d
+        total = self.vocab * d * (1 if self.tie_embeddings else 2)
         for i in range(self.n_layers):
             desc = self.pattern[i % self.period]
             if desc.mixer in (MIXER_ATTN, MIXER_ATTN_LOCAL):
                 total += qkv
+            elif desc.mixer == MIXER_MAMBA and self.ssm_groups:
+                di, gn = self.ssm_inner, self.ssm_groups * self.ssm_state
+                total += d * (2 * di + 2 * gn + self.ssm_nheads) + di * d
             elif desc.mixer == MIXER_MAMBA:
                 di = 2 * d
                 total += 2 * d * di + di * d + di * (2 * self.ssm_state + 2)
@@ -128,9 +179,11 @@ class ArchConfig:
             elif desc.ffn in (FFN_MOE, FFN_MOE_DENSE):
                 m = self.moe
                 de = m.d_expert or ff
-                total += m.num_experts * 3 * d * de + d * m.num_experts
+                total += m.num_experts * m.expert_mats() * d * de + \
+                    d * m.num_experts
                 if m.num_shared:
-                    total += m.num_shared * 3 * d * de
+                    total += m.num_shared * m.expert_mats() * d * \
+                        (m.d_shared or de)
                 if desc.ffn == FFN_MOE_DENSE:
                     total += dense_ffn
         if self.enc_dec:
@@ -149,7 +202,7 @@ class ArchConfig:
         for i in range(self.n_layers):
             desc = self.pattern[i % self.period]
             if desc.ffn in (FFN_MOE, FFN_MOE_DENSE):
-                inactive = (m.num_experts - m.top_k) * 3 * d * de
+                inactive = (m.num_experts - m.top_k) * m.expert_mats() * d * de
                 total -= inactive
         return int(total)
 
@@ -211,5 +264,6 @@ def _ensure_loaded():
     import importlib
     for mod in ("starcoder2_3b", "qwen3_8b", "mistral_large_123b", "gemma2_9b",
                 "arctic_480b", "deepseek_moe_16b", "whisper_base",
-                "qwen2_vl_7b", "xlstm_125m", "jamba_1_5_large_398b"):
+                "qwen2_vl_7b", "xlstm_125m", "jamba_1_5_large_398b",
+                "nemotron_3_nano_30b_a3b"):
         importlib.import_module(f"repro.configs.{mod}")

@@ -26,6 +26,9 @@ import numpy as np
 from jax.extend import core as jcore
 
 from repro.core.graph import DataflowGraph, MAX_SHAPE_RANK, op_id, topo_relabel
+from repro.obs import jaxprof
+from repro.obs.metrics import MetricsRegistry
+from repro.obs.trace import get_tracer
 
 _PRIM_TO_OP = {
     "dot_general": "matmul",
@@ -47,14 +50,16 @@ _PRIM_TO_OP = {
     "concatenate": "concat", "slice": "split", "transpose": "transpose",
     "reshape": "reshape", "broadcast_in_dim": "reshape", "squeeze": "reshape",
     "iota": "other", "rev": "transpose", "pad": "reshape",
-    "scan": "scan", "while": "scan", "pjit": "scan", "closed_call": "scan",
+    "scan": "scan", "while": "scan", "pjit": "scan", "jit": "scan",
+    "closed_call": "scan",
     "custom_vjp_call": "scan", "remat": "scan", "checkpoint": "scan",
     "all_reduce": "collective", "all_gather": "collective",
     "psum": "collective", "all_to_all": "collective",
     "reduce_scatter": "collective", "ppermute": "collective",
 }
 
-_FUSED = {"scan", "while", "pjit", "closed_call", "custom_vjp_call",
+# the call primitive is "jit" in the installed jax, "pjit" in older releases
+_FUSED = {"scan", "while", "pjit", "jit", "closed_call", "custom_vjp_call",
           "custom_jvp_call", "remat", "checkpoint", "cond"}
 
 
@@ -277,13 +282,17 @@ _MAX_EXPAND_DEPTH = 12
 # jax's current checkpoint primitive: the legacy fused path predates it
 # and treats it as a plain node (kept bit-identical), but expansion must
 # inline it or every layer body stays hidden inside the checkpoint.
-_EXPANDABLE = {"scan", "pjit", "closed_call", "custom_vjp_call",
+_EXPANDABLE = {"scan", "pjit", "jit", "closed_call", "custom_vjp_call",
                "custom_jvp_call", "remat", "checkpoint", "remat2"}
 
 
 def _walk(acc: _Accum, jaxpr, env: Dict[Any, int],
           expand, depth: int) -> None:
     for eqn in jaxpr.eqns:
+        if not eqn.outvars:
+            # nothing downstream can read it (grad leaves calls whose
+            # every output was dead code with their outputs pruned)
+            continue
         pname = eqn.primitive.name
         if pname in _FUSED or (expand and pname in _EXPANDABLE):
             if (expand and depth < _MAX_EXPAND_DEPTH
@@ -364,21 +373,39 @@ def extract(fn: Callable, *args, name: str = "jaxpr",
 # Model-zoo extraction with a content-addressed disk cache.
 # ---------------------------------------------------------------------------
 CACHE_ENV = "REPRO_JAXPR_CACHE"
-_DEFAULT_CACHE = os.path.join(".cache", "jaxprs")
+# beside the compile cache, <repo>/.cache/jaxprs (gitignored)
+_DEFAULT_CACHE = os.path.join(os.path.dirname(jaxprof.REPO_CACHE_DIR),
+                              "jaxprs")
+
+# graph_extract_total{result=hit|miss}: extract_arch calls by disk-cache
+# result; graph_extract_nodes: the node count of the last graph returned
+METRICS = MetricsRegistry()
+_EXTRACTS = METRICS.counter("graph_extract_total",
+                            "model-zoo graph extractions by disk-cache result",
+                            ("result",))
+_NODES = METRICS.gauge("graph_extract_nodes",
+                       "nodes of the last extracted model-zoo graph")
+
+
+def _arch_config(arch_name: str, reduced: bool, layers: Optional[int]):
+    from repro.configs import get_config, get_reduced
+    cfg = get_reduced(arch_name) if reduced else get_config(arch_name)
+    return cfg if layers is None else cfg.first_layers(layers)
 
 
 def arch_digest(arch_name: str, *, reduced: bool = False,
                 mode: str = "loss", seq: Optional[int] = None,
-                batch: int = 8, expand: Optional[int] = None) -> str:
+                batch: int = 8, expand: Optional[int] = None,
+                layers: Optional[int] = None) -> str:
     """Stable hash of everything that determines an extracted arch graph:
-    the full :class:`~repro.configs.base.ArchConfig` contents plus the
-    trace shape and expansion settings.  Repeated campaign runs key the
-    disk cache on this, so a config edit re-traces and a rerun doesn't."""
-    from repro.configs import get_config, get_reduced
-    cfg = get_reduced(arch_name) if reduced else get_config(arch_name)
+    the full :class:`~repro.configs.base.ArchConfig` contents (cut to
+    ``layers``) plus the trace shape and expansion settings.  Repeated
+    campaign runs key the disk cache on this, so a config edit re-traces
+    and a rerun doesn't."""
+    cfg = _arch_config(arch_name, reduced, layers)
     payload = json.dumps(
         {"cfg": dataclasses.asdict(cfg), "reduced": reduced, "mode": mode,
-         "seq": seq, "batch": batch, "expand": expand, "v": 2},
+         "seq": seq, "batch": batch, "expand": expand, "v": 3},
         sort_keys=True, default=str)
     return hashlib.sha256(payload.encode()).hexdigest()
 
@@ -400,11 +427,26 @@ def _graph_from_npz(path: str) -> DataflowGraph:
     return g
 
 
+def _batch_avals(cfg, batch: int, seq: int) -> Dict[str, Any]:
+    """The training batch's avals, frontend stubs included (whisper's
+    frames, qwen2-vl's patches and M-RoPE positions), with the patches cut
+    to ``seq`` where the sequence is shorter."""
+    from repro.configs.base import ShapeConfig
+    from repro.launch.input_specs import train_batch_specs
+    out = train_batch_specs(cfg, ShapeConfig("extract", seq, batch, "train"))
+    pe = out.get("patch_embeds")
+    if pe is not None and pe.shape[1] > seq:
+        out["patch_embeds"] = jax.ShapeDtypeStruct(
+            (batch, seq, pe.shape[2]), pe.dtype)
+    return out
+
+
 def extract_arch(arch_name: str, *, reduced: bool = False,
                  mode: str = "loss", seq: Optional[int] = None,
                  batch: int = 8, expand: Optional[int] = None,
                  cache_dir: Optional[str] = None,
-                 max_nodes: int = 2_000_000) -> DataflowGraph:
+                 max_nodes: int = 2_000_000,
+                 layers: Optional[int] = None) -> DataflowGraph:
     """Extract a model-zoo architecture's dataflow graph, disk-cached.
 
     ``mode`` is ``"loss"`` (forward + loss) or ``"grad"`` (forward +
@@ -415,29 +457,45 @@ def extract_arch(arch_name: str, *, reduced: bool = False,
     ``jax.eval_shape``/``ShapeDtypeStruct`` throughout, so a 398B-param
     config costs abstract shapes, not memory.
 
-    Results are cached under ``cache_dir`` (default ``$REPRO_JAXPR_CACHE``
-    or ``.cache/jaxprs``) keyed by :func:`arch_digest` — re-running a
-    jumbo campaign never re-traces an unchanged config.
-    """
-    digest = arch_digest(arch_name, reduced=reduced, mode=mode, seq=seq,
-                         batch=batch, expand=expand)
-    cache_dir = cache_dir or os.environ.get(CACHE_ENV, _DEFAULT_CACHE)
-    path = os.path.join(cache_dir, f"{arch_name}-{digest[:16]}.npz")
-    if os.path.exists(path):
-        return _graph_from_npz(path)
+    ``layers`` keeps the first layers of the architecture, which must be
+    whole periods (:meth:`~repro.configs.base.ArchConfig.first_layers`).
 
-    from repro.configs import get_config, get_reduced
-    from repro.models.model import build_model
-    cfg = get_reduced(arch_name) if reduced else get_config(arch_name)
-    model = build_model(cfg)
-    params = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
-    s = int(seq if seq is not None else 4096)
-    tok = jax.ShapeDtypeStruct((batch, s), np.int32)
-    batch_avals = {"tokens": tok, "labels": tok}
-    fn = model.loss if mode == "loss" else (
-        lambda p, b: jax.grad(model.loss)(p, b))
+    Results are cached under ``cache_dir`` (default ``$REPRO_JAXPR_CACHE``
+    or ``<repo>/.cache/jaxprs``) keyed by :func:`arch_digest` — re-running
+    a jumbo campaign never re-traces an unchanged config.  Each call opens
+    the span ``graph.extract`` and counts ``graph_extract_total{result}``
+    in :data:`METRICS`.
+    """
     if mode not in ("loss", "grad"):
         raise ValueError(f"extract_arch: unknown mode {mode!r}")
+    cfg = _arch_config(arch_name, reduced, layers)
+    s = int(seq if seq is not None else 4096)
+    with get_tracer().span("graph.extract", cat="graph", arch=arch_name,
+                           mode=mode, seq=s, layers=cfg.n_layers,
+                           expand=expand) as sp:
+        digest = arch_digest(arch_name, reduced=reduced, mode=mode, seq=seq,
+                             batch=batch, expand=expand, layers=layers)
+        cache_dir = cache_dir or os.environ.get(CACHE_ENV, _DEFAULT_CACHE)
+        path = os.path.join(cache_dir, f"{arch_name}-{digest[:16]}.npz")
+        hit = os.path.exists(path)
+        g = (_graph_from_npz(path) if hit else
+             _trace_arch(cfg, arch_name, reduced, mode, s, batch, expand,
+                         max_nodes, cache_dir, path))
+        sp.set(nodes=g.num_nodes, cache_hit=hit)
+    _EXTRACTS.inc(result="hit" if hit else "miss")
+    _NODES.set(g.num_nodes)
+    return g
+
+
+def _trace_arch(cfg, arch_name, reduced, mode, s, batch, expand, max_nodes,
+                cache_dir, path) -> DataflowGraph:
+    """Trace ``cfg``'s loss or gradient and store the graph at ``path``."""
+    from repro.models.model import build_model
+    model = build_model(cfg)
+    params = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+    batch_avals = _batch_avals(cfg, batch, s)
+    fn = model.loss if mode == "loss" else (
+        lambda p, b: jax.grad(model.loss)(p, b))
     name = f"{arch_name}{'-r' if reduced else ''}-{mode}-s{s}"
     g = extract(fn, params, batch_avals, name=name, expand=expand,
                 max_nodes=max_nodes)

@@ -11,10 +11,13 @@ sized so that the simulator's step times land in the paper's regime
 All generators accept ``time_steps``/``scale`` so tests use small instances
 while benchmarks can reproduce paper-scale node counts (8-layer GNMT with
 ``time_steps=128`` exceeds 50k nodes).
+
+``model_step`` is the one family that is traced, not generated: a
+model-zoo architecture's training step, extracted from its JAX program.
 """
 from __future__ import annotations
 
-from typing import Callable, Dict, List, Sequence
+from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
@@ -277,6 +280,21 @@ def wavenet(stacks: int = 2, layers_per_stack: int = 18, batch: int = 8,
     return b.build()
 
 
+def model_step(arch: str, mode: str = "grad", seq: int = 4096,
+               batch: int = 8, layers: Optional[int] = None,
+               expand: Optional[int] = None,
+               reduced: bool = False) -> DataflowGraph:
+    """A model-zoo architecture's training step (``mode="grad"``: forward
+    and backward; ``"loss"``: forward and loss) as a dataflow graph, traced
+    through :func:`~repro.graphs.jaxpr_extract.extract_arch` and its disk
+    cache.  ``layers`` keeps the first layers (whole periods); ``expand``
+    unrolls scans of up to that many trips; ``reduced`` takes the
+    architecture's tiny test widths."""
+    from repro.graphs.jaxpr_extract import extract_arch
+    return extract_arch(arch, reduced=reduced, mode=mode, seq=seq,
+                        batch=batch, expand=expand, layers=layers)
+
+
 # --------------------------------------------------------------------------
 # Registry
 # --------------------------------------------------------------------------
@@ -287,6 +305,7 @@ FAMILIES: Dict[str, Callable[..., DataflowGraph]] = {
     "inception": inception,
     "amoebanet": amoebanet,
     "wavenet": wavenet,
+    "model_step": model_step,
 }
 
 

@@ -3,7 +3,8 @@
 A *group* is one period of the architecture's layer pattern (DESIGN.md §4);
 the model scans over ``n_groups`` stacked copies.  Each layer in a group is
 pre-norm: ``x + mixer(norm(x))`` then ``x + ffn(norm(x))`` (plus MoE aux
-loss and, for arctic, the parallel dense residual).
+loss and, for arctic, the parallel dense residual); a layer without a
+mixer (``MIXER_NONE``) or without an FFN (``FFN_NONE``) skips that half.
 """
 from __future__ import annotations
 
@@ -15,7 +16,7 @@ import jax.numpy as jnp
 from repro.configs.base import (ArchConfig, FFN_DENSE, FFN_MOE,
                                 FFN_MOE_DENSE, FFN_NONE, LayerDesc,
                                 MIXER_ATTN, MIXER_ATTN_LOCAL, MIXER_MAMBA,
-                                MIXER_MLSTM, MIXER_SLSTM)
+                                MIXER_MLSTM, MIXER_NONE, MIXER_SLSTM)
 from repro.models import layers as L
 from repro.models import moe as M
 from repro.models import ssm as S
@@ -24,7 +25,9 @@ from repro.models import ssm as S
 def init_layer(key, cfg: ArchConfig, desc: LayerDesc, dtype,
                cross_attn: bool = False) -> Dict[str, Any]:
     ks = jax.random.split(key, 6)
-    p: Dict[str, Any] = {"norm1": jnp.zeros((cfg.d_model,), dtype)}
+    p: Dict[str, Any] = {}
+    if desc.mixer != MIXER_NONE:
+        p["norm1"] = jnp.zeros((cfg.d_model,), dtype)
     if desc.mixer in (MIXER_ATTN, MIXER_ATTN_LOCAL):
         p["attn"] = L.init_attention(ks[0], cfg, dtype)
     elif desc.mixer == MIXER_MAMBA:
@@ -69,16 +72,11 @@ def _cache_dtype_of(name: str) -> Any:
     return None
 
 
-def apply_layer(p: Dict[str, Any], x: jnp.ndarray, cfg: ArchConfig,
-                desc: LayerDesc, *, positions, mode: str,
-                cache: Optional[Dict[str, Any]] = None,
-                cache_pos=None, enc_out: Optional[jnp.ndarray] = None,
-                causal: bool = True
-                ) -> Tuple[jnp.ndarray, Optional[Dict[str, Any]], jnp.ndarray]:
-    """mode: "train" | "prefill" | "decode".  Returns (x, new_cache, aux)."""
-    aux = jnp.float32(0.0)
-    new_cache: Dict[str, Any] = {} if cache is not None else None
-    h = L.rmsnorm(p["norm1"], x)
+def _mixer(p: Dict[str, Any], h: jnp.ndarray, cfg: ArchConfig,
+           desc: LayerDesc, *, positions, mode: str, cache, cache_pos,
+           new_cache: Optional[Dict[str, Any]], causal: bool) -> jnp.ndarray:
+    """The layer's token mixer on the normed stream ``h``; writes its decode
+    state into ``new_cache``."""
     if desc.mixer in (MIXER_ATTN, MIXER_ATTN_LOCAL):
         local = desc.mixer == MIXER_ATTN_LOCAL
         attn_cache = (cache["k"], cache["v"]) if cache is not None else None
@@ -108,11 +106,28 @@ def apply_layer(p: Dict[str, Any], x: jnp.ndarray, cfg: ArchConfig,
             new_cache.update(ns)
     else:
         raise ValueError(desc.mixer)
-    x = x + y
+    return y
+
+
+def apply_layer(p: Dict[str, Any], x: jnp.ndarray, cfg: ArchConfig,
+                desc: LayerDesc, *, positions, mode: str,
+                cache: Optional[Dict[str, Any]] = None,
+                cache_pos=None, enc_out: Optional[jnp.ndarray] = None,
+                causal: bool = True
+                ) -> Tuple[jnp.ndarray, Optional[Dict[str, Any]], jnp.ndarray]:
+    """mode: "train" | "prefill" | "decode".  Returns (x, new_cache, aux)."""
+    aux = jnp.float32(0.0)
+    new_cache: Dict[str, Any] = {} if cache is not None else None
+    eps = cfg.norm_eps
+    if desc.mixer != MIXER_NONE:
+        x = x + _mixer(p, L.rmsnorm(p["norm1"], x, eps), cfg, desc,
+                       positions=positions, mode=mode, cache=cache,
+                       cache_pos=cache_pos, new_cache=new_cache,
+                       causal=causal)
 
     if "xattn" in p and (enc_out is not None or
                          (cache is not None and "xk" in cache)):
-        hx = L.rmsnorm(p["norm_x"], x)
+        hx = L.rmsnorm(p["norm_x"], x, eps)
         if mode == "decode" and cache is not None and "xk" in cache:
             # cross K/V precomputed at prefill
             y = L.decode_attention(
@@ -137,7 +152,7 @@ def apply_layer(p: Dict[str, Any], x: jnp.ndarray, cfg: ArchConfig,
 
     if desc.ffn == FFN_NONE:
         return x, new_cache, aux
-    h2 = L.rmsnorm(p["norm2"], x)
+    h2 = L.rmsnorm(p["norm2"], x, eps)
     if desc.ffn == FFN_DENSE:
         x = x + L.mlp_block(p["mlp"], h2)
     elif desc.ffn == FFN_MOE:

@@ -6,6 +6,7 @@ driven by ``lax.scan`` — compile time is O(1) in depth, which keeps the
 
     params = {
       "embed":   [V, D]                    (tied LM head by default)
+      "lm_head": [V, D]                    (untied head, tie_embeddings=False)
       "groups":  {"slot0": {...}, ...}     leaves [G, ...]
       "enc":     {...}                     (whisper encoder, optional)
       "final_norm": [D]
@@ -105,6 +106,10 @@ class Model:
             "groups": groups,
             "final_norm": jnp.zeros((cfg.d_model,), dtype),
         }
+        if not cfg.tie_embeddings:
+            params["lm_head"] = jax.random.normal(
+                jax.random.fold_in(k_emb, 1), (cfg.vocab, cfg.d_model),
+                dtype) * cfg.d_model ** -0.5
         if cfg.enc_dec:
             ekeys = jax.random.split(k_enc, cfg.n_enc_layers)
             params["enc"] = {
@@ -137,7 +142,7 @@ class Model:
 
         x, _ = jax.lax.scan(body, frames.astype(_dt(cfg.activ_dtype)),
                             params["enc"]["layers"])
-        return L.rmsnorm(params["enc"]["final_norm"], x)
+        return L.rmsnorm(params["enc"]["final_norm"], x, cfg.norm_eps)
 
     # ------------------------------------------------------------ forward
     def _embed_tokens(self, params, batch: Dict[str, jnp.ndarray]) -> jnp.ndarray:
@@ -184,8 +189,13 @@ class Model:
         (x, aux), new_caches = jax.lax.scan(
             group_body, (self._c_hidden(x), jnp.float32(0.0)),
             (params["groups"], caches))
-        x = L.rmsnorm(params["final_norm"], self._c_hidden(x))
+        x = L.rmsnorm(params["final_norm"], self._c_hidden(x), cfg.norm_eps)
         return x, new_caches, aux
+
+    def head(self, params) -> jnp.ndarray:
+        """The LM head's [V, D] rows: the embedding unless untied."""
+        return params["embed"] if self.cfg.tie_embeddings else \
+            params["lm_head"]
 
     # --------------------------------------------------------------- loss
     def loss(self, params, batch: Dict[str, jnp.ndarray]) -> jnp.ndarray:
@@ -198,7 +208,7 @@ class Model:
             enc_out = self._encode(params, batch["frames"])
         h, _, aux = self.backbone(params, x, positions, mode="train",
                                   enc_out=enc_out)
-        ce = L.chunked_cross_entropy(h, params["embed"], batch["labels"],
+        ce = L.chunked_cross_entropy(h, self.head(params), batch["labels"],
                                      chunk=cfg.logits_chunk,
                                      final_softcap=cfg.final_softcap)
         return ce + 0.01 * aux / max(cfg.n_layers, 1)
@@ -293,7 +303,7 @@ class Model:
         h, new_caches, _ = self.backbone(params, x, positions, mode="prefill",
                                          caches=caches, enc_out=enc_out)
         logits = h[:, -1:].astype(jnp.float32) @ \
-            params["embed"].astype(jnp.float32).T
+            self.head(params).astype(jnp.float32).T
         if cfg.final_softcap:
             logits = L._softcap(logits, cfg.final_softcap)
         return new_caches, logits
@@ -312,7 +322,7 @@ class Model:
                                          caches=caches, cache_pos=pos,
                                          enc_out=None)
         logits = h[:, -1:].astype(jnp.float32) @ \
-            params["embed"].astype(jnp.float32).T
+            self.head(params).astype(jnp.float32).T
         if cfg.final_softcap:
             logits = L._softcap(logits, cfg.final_softcap)
         return new_caches, logits

@@ -12,6 +12,11 @@ DESIGN.md §8: mLSTM/sLSTM use sigmoid input gates instead of stabilized
 exponential gating (the max-stabilizer m_t is unnecessary with bounded
 gates); Mamba uses the scalar-decay-per-head SSD parameterization
 (Mamba-2) rather than Mamba-1's diagonal A, which is the TPU-native form.
+
+With ``cfg.ssm_groups`` set, ``mamba_block`` is Mamba-2's own layer, as
+Nemotron-H has it: one in-projection to (z, x, B, C, dt), the causal conv
+(with bias) over x, B and C together, B and C per group of heads, and a
+gated RMSNorm, ``norm(y * silu(z))`` per group, before the out-projection.
 """
 from __future__ import annotations
 
@@ -105,12 +110,31 @@ def gla_step(q, k, v, log_g, s_in, state, norm=None):
 
 
 # ------------------------------------------------------------------- mamba
+def _conv_dim(cfg: ArchConfig) -> int:
+    """Channels of Mamba's causal conv: x alone, or x, B and C (Mamba-2)."""
+    if cfg.ssm_groups:
+        return cfg.ssm_inner + 2 * cfg.ssm_groups * cfg.ssm_state
+    return cfg.ssm_inner
+
+
 def init_mamba(key, cfg: ArchConfig, dtype) -> Dict[str, Any]:
     d = cfg.d_model
-    di = 2 * d
-    hs = cfg.ssm_heads or max(di // 128, 1)
+    di = cfg.ssm_inner
+    hs = cfg.ssm_nheads
     N = cfg.ssm_state
     ks = jax.random.split(key, 6)
+    if cfg.ssm_groups:
+        cd = _conv_dim(cfg)
+        return {
+            "w_in": jax.random.normal(ks[0], (d, di + cd + hs), dtype) * d ** -0.5,
+            "conv": jax.random.normal(ks[1], (4, cd), dtype) * 0.2,
+            "conv_b": jnp.zeros((cd,), dtype),
+            "dt_bias": jnp.zeros((hs,), jnp.float32),
+            "a_log": jnp.zeros((hs,), jnp.float32),
+            "d_skip": jnp.ones((hs,), jnp.float32),
+            "norm": jnp.zeros((di,), dtype),
+            "w_out": jax.random.normal(ks[4], (di, d), dtype) * di ** -0.5,
+        }
     return {
         "w_in": jax.random.normal(ks[0], (d, 2 * di), dtype) * d ** -0.5,
         "conv": jax.random.normal(ks[1], (4, di), dtype) * 0.2,
@@ -123,8 +147,8 @@ def init_mamba(key, cfg: ArchConfig, dtype) -> Dict[str, Any]:
     }
 
 
-def _causal_conv(x, w, conv_state=None):
-    """Depthwise causal conv, width 4.  x: [B,S,C]; w: [4,C].
+def _causal_conv(x, w, conv_state=None, bias=None):
+    """Depthwise causal conv, width 4.  x: [B,S,C]; w: [4,C]; bias: [C].
 
     With ``conv_state`` [B,3,C] (decode), prepends it instead of zeros and
     returns the updated state.
@@ -136,6 +160,8 @@ def _causal_conv(x, w, conv_state=None):
         pad = conv_state.astype(x.dtype)
     xp = jnp.concatenate([pad, x], axis=1)                # [B,S+3,C]
     out = sum(xp[:, i:i + s] * w[i][None, None, :] for i in range(4))
+    if bias is not None:
+        out = out + bias[None, None, :]
     new_state = xp[:, -3:]
     return out, new_state
 
@@ -145,9 +171,10 @@ def mamba_block(p, x, cfg: ArchConfig, state=None, decode: bool = False):
 
     state = {"ssm": [B,H,N,P], "conv": [B,3,di]} (decode) or None (train).
     """
+    if cfg.ssm_groups:
+        return _mamba2_block(p, x, cfg, state, decode)
     b, s, d = x.shape
-    di = 2 * d
-    hs = cfg.ssm_heads or max(di // 128, 1)
+    di, hs = cfg.ssm_inner, cfg.ssm_nheads
     N = cfg.ssm_state
     P = di // hs
     zx = x @ p["w_in"]
@@ -159,28 +186,63 @@ def mamba_block(p, x, cfg: ArchConfig, state=None, decode: bool = False):
     Bm, Cm = jnp.split(bc, 2, axis=-1)                    # [B,S,N]
     dt = jax.nn.softplus((x @ p["w_dt"]).astype(jnp.float32) +
                          p["dt_bias"])                    # [B,S,H]
-    a = -jnp.exp(p["a_log"])                              # [H]
-    log_g = dt * a[None, None, :]
     xh = xin.reshape(b, s, hs, P)
     Bh = jnp.broadcast_to(Bm[:, :, None, :], (b, s, hs, N))
     Ch = jnp.broadcast_to(Cm[:, :, None, :], (b, s, hs, N))
-    ssm_state = state["ssm"] if state is not None else \
-        jnp.zeros((b, hs, N, P), jnp.float32)
-    if decode:
-        y, S_end, _ = gla_step(Ch, Bh, xh, log_g, dt, ssm_state)
-    else:
-        y, S_end, _ = gla_chunked(Ch, Bh, xh, log_g, dt, ssm_state,
-                                  chunk=256)
-    y = y + xh * p["d_skip"][None, None, :, None].astype(y.dtype)
+    y, S_end = _ssd(p, xh, Bh, Ch, dt, state, decode, cfg.ssm_chunk)
     y = (y.reshape(b, s, di) * jax.nn.silu(z)) @ p["w_out"]
     return y, {"ssm": S_end, "conv": new_conv}
 
 
+def _ssd(p, xh, Bh, Ch, dt, state, decode: bool, chunk: int):
+    """The SSD recurrence over heads, chunked (train, prefill) or one step
+    (decode), plus the D skip.  xh: [B,S,H,P]; Bh, Ch: [B,S,H,N]; dt:
+    [B,S,H].  Returns (y [B,S,H,P], final state [B,H,N,P])."""
+    b, s, hs, P = xh.shape
+    log_g = dt * -jnp.exp(p["a_log"])[None, None, :]
+    ssm_state = state["ssm"] if state is not None else \
+        jnp.zeros((b, hs, Bh.shape[-1], P), jnp.float32)
+    if decode:
+        y, S_end, _ = gla_step(Ch, Bh, xh, log_g, dt, ssm_state)
+    else:
+        y, S_end, _ = gla_chunked(Ch, Bh, xh, log_g, dt, ssm_state,
+                                  chunk=chunk)
+    return y + xh * p["d_skip"][None, None, :, None].astype(y.dtype), S_end
+
+
+def gated_rmsnorm(scale, y, z, groups: int, eps: float):
+    """Mamba-2's gated norm: RMSNorm of ``y * silu(z)`` over each of
+    ``groups`` equal slices of the last axis, times ``1 + scale``."""
+    dt = y.dtype
+    h = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    hg = h.reshape(*h.shape[:-1], groups, h.shape[-1] // groups)
+    hg = hg * jax.lax.rsqrt(jnp.mean(hg * hg, axis=-1, keepdims=True) + eps)
+    return (hg.reshape(h.shape) * (1.0 + scale.astype(jnp.float32))).astype(dt)
+
+
+def _mamba2_block(p, x, cfg: ArchConfig, state, decode: bool):
+    """Mamba-2 with B and C per group (module docstring)."""
+    b, s, _ = x.shape
+    di, hs, N, G = cfg.ssm_inner, cfg.ssm_nheads, cfg.ssm_state, cfg.ssm_groups
+    P = di // hs
+    z, xbc, dt = jnp.split(x @ p["w_in"], [di, di + _conv_dim(cfg)], axis=-1)
+    conv_state = state["conv"] if state is not None else None
+    xbc, new_conv = _causal_conv(xbc, p["conv"], conv_state, p["conv_b"])
+    xin, Bm, Cm = jnp.split(jax.nn.silu(xbc), [di, di + G * N], axis=-1)
+    dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])   # [B,S,H]
+    xh = xin.reshape(b, s, hs, P)
+    # head h reads group h // (hs / G)
+    Bh = jnp.repeat(Bm.reshape(b, s, G, N), hs // G, axis=2)
+    Ch = jnp.repeat(Cm.reshape(b, s, G, N), hs // G, axis=2)
+    y, S_end = _ssd(p, xh, Bh, Ch, dt, state, decode, cfg.ssm_chunk)
+    y = gated_rmsnorm(p["norm"], y.reshape(b, s, di), z, G, cfg.norm_eps)
+    return y @ p["w_out"], {"ssm": S_end, "conv": new_conv}
+
+
 def mamba_state_shape(cfg: ArchConfig, batch: int):
-    di = 2 * cfg.d_model
-    hs = cfg.ssm_heads or max(di // 128, 1)
-    return {"ssm": (batch, hs, cfg.ssm_state, di // hs),
-            "conv": (batch, 3, di)}
+    hs = cfg.ssm_nheads
+    return {"ssm": (batch, hs, cfg.ssm_state, cfg.ssm_inner // hs),
+            "conv": (batch, 3, _conv_dim(cfg))}
 
 
 # ------------------------------------------------------------------- mLSTM

@@ -2,9 +2,12 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
+from repro.configs import list_archs
 from repro.core.featurize import featurize
-from repro.graphs.jaxpr_extract import extract
+from repro.core.graph import op_id
+from repro.graphs.jaxpr_extract import extract, extract_arch
 from repro.sim import p100_topology, prepare_sim_graph
 from repro.sim.scheduler import Env
 
@@ -122,3 +125,67 @@ def test_extract_arch_digest_keys_config(tmp_path):
     assert arch_digest("qwen3-8b", mode="loss", seq=64, expand=8) != base
     assert arch_digest("qwen3-8b", mode="grad", seq=128, expand=8) != base
     assert arch_digest("qwen3-8b", mode="grad", seq=64, expand=16) != base
+
+
+# ---------------------------------------------------------------------------
+# the call primitive of the installed jax, and the model zoo's gradients
+# ---------------------------------------------------------------------------
+def test_jitted_sub_function_is_seen_through():
+    """A ``jax.jit`` call inside the traced function is a call primitive
+    like ``pjit`` was: fused, it costs its body's FLOPs; expanded, its
+    matmul is a node of its own."""
+    inner = jax.jit(lambda a, b: jnp.tanh(a @ b))
+
+    def fn(x, w):
+        return jnp.sum(inner(x, w))
+
+    x, w = jnp.zeros((8, 32)), jnp.zeros((32, 16))
+    mm = 2.0 * 8 * 32 * 16
+    fused = extract(fn, x, w, name="fused")
+    assert fused.total_flops() >= mm
+    big = extract(fn, x, w, name="big", expand=4)
+    is_mm = big.op_type == op_id("matmul")
+    assert int(is_mm.sum()) == 1 and big.flops[is_mm][0] == mm
+    np.testing.assert_allclose(big.total_flops(), fused.total_flops())
+
+
+@pytest.mark.parametrize("arch", list_archs())
+def test_grad_of_every_reduced_config_extracts(arch, tmp_path):
+    g = extract_arch(arch, reduced=True, mode="grad", seq=32, batch=2,
+                     cache_dir=str(tmp_path))
+    g.validate()
+    loss = extract_arch(arch, reduced=True, mode="loss", seq=32, batch=2,
+                        cache_dir=str(tmp_path))
+    assert g.num_nodes > loss.num_nodes
+    assert int(g.op_type.max()) < 25          # featurize embeds 25 op ids
+
+
+# (nodes, total FLOPs) of each reduced config's gradient graph, seq 32,
+# batch 2, expand 8, pinned with the repaired extractor before the zoo's
+# model code took Nemotron-H's layers; nemotron's own row pinned with it
+PINNED = {
+    "arctic-480b": (1355, 88810594.0),
+    "deepseek-moe-16b": (1371, 85534184.0),
+    "gemma2-9b": (1164, 51041976.0),
+    "jamba-1.5-large-398b": (5600, 256222605.0),
+    "mistral-large-123b": (986, 41710160.0),
+    "qwen2-vl-7b": (1016, 42786752.0),
+    "qwen3-8b": (1140, 41902416.0),
+    "starcoder2-3b": (986, 41710160.0),
+    "whisper-base": (3277, 9818466806.0),
+    "xlstm-125m": (763, 44013407.0),
+    "nemotron-3-nano-30b-a3b": (4093, 84319839.0),
+}
+
+
+def test_every_config_is_pinned():
+    assert set(PINNED) == set(list_archs())
+
+
+@pytest.mark.parametrize("arch", sorted(PINNED))
+def test_reduced_grad_graph_keeps_its_pinned_size(arch, tmp_path):
+    g = extract_arch(arch, reduced=True, mode="grad", seq=32, batch=2,
+                     expand=8, cache_dir=str(tmp_path))
+    nodes, flops = PINNED[arch]
+    assert g.num_nodes == nodes
+    assert g.total_flops() == pytest.approx(flops, rel=1e-9)

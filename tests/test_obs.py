@@ -280,3 +280,31 @@ def test_spans_share_the_profiler_clock():
     assert progs
     for s, e in progs:
         assert b0 - 1e-3 <= s and e <= b1 + 1e-3, (s - b0, e - b1)
+
+
+# ------------------------------------------------------- graph extraction
+def test_graph_extract_span_and_cache_counters(tmp_path):
+    """Each ``extract_arch`` call opens one ``graph.extract`` span, with its
+    shape and result, and counts a miss on a cold disk cache, then a hit."""
+    from repro.graphs import jaxpr_extract as jx
+    hits = jx.METRICS.counter("graph_extract_total", "", ("result",))
+    h0, m0 = hits.get(result="hit"), hits.get(result="miss")
+    tracer = Tracer(enabled=True)
+    old = set_tracer(tracer)
+    try:
+        kw = dict(reduced=True, mode="grad", seq=16, batch=2, expand=4,
+                  cache_dir=str(tmp_path))
+        cold = jx.extract_arch("starcoder2-3b", **kw)
+        warm = jx.extract_arch("starcoder2-3b", **kw)
+    finally:
+        set_tracer(old)
+    spans = [s for s in tracer.spans if s.name == "graph.extract"]
+    assert len(spans) == 2
+    want = dict(arch="starcoder2-3b", mode="grad", seq=16, layers=2,
+                expand=4, nodes=cold.num_nodes)
+    assert spans[0].args == dict(want, cache_hit=False)
+    assert spans[1].args == dict(want, cache_hit=True)
+    assert warm.num_nodes == cold.num_nodes
+    assert hits.get(result="miss") == m0 + 1
+    assert hits.get(result="hit") == h0 + 1
+    assert jx.METRICS.get("graph_extract_nodes").get() == cold.num_nodes
