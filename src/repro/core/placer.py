@@ -174,6 +174,43 @@ def _mask_full_devices(logits: jnp.ndarray, mem_used: jnp.ndarray,
 
 
 # ------------------------------------------------------------ teacher-forced
+def _band_attention(q, kbuf, vbuf, base, window: int) -> jnp.ndarray:
+    """Causal W-band attention as blocked windows of dense products.
+
+    q: [S, heads, hd]; kbuf/vbuf: [W-1+S, heads, hd] (the W-1 columns
+    before q[0], then q's own); ``base``: global index of q[0], traced or
+    not.  Query ``i`` attends buffer columns ``[i, i + W - 1]`` whose
+    global index ``base + col - (W-1)`` is >= 0 — the AR ring buffer's
+    mask (j <= i, i - j < W, inclusive self).
+
+    Queries go in blocks of W rows; block b's band lies in buffer blocks
+    b and b+1, so its keys are one static concat and its scores one dense
+    [heads, W, 2W] tile, masked to the band.  Twice the band's products,
+    but no gather, scatter or dynamic index, in the pass or its gradient;
+    a W that does not divide S, or exceeds it, only adds padding.
+    """
+    s, heads, hd = q.shape
+    w = window
+    nb = -(-s // w)
+    qb = jnp.pad(q, ((0, nb * w - s), (0, 0), (0, 0))).reshape(
+        nb, w, heads, hd)
+
+    def pairs(buf):                              # [nb, 2W, heads, hd]
+        blk = jnp.pad(buf, ((0, (nb + 1) * w - buf.shape[0]), (0, 0),
+                            (0, 0))).reshape(nb + 1, w, heads, hd)
+        return jnp.concatenate([blk[:-1], blk[1:]], axis=1)
+
+    kb, vb = pairs(kbuf), pairs(vbuf)
+    sc = jnp.einsum("bihd,bjhd->bhij", qb, kb) / jnp.sqrt(jnp.float32(hd))
+    rel = jnp.arange(2 * w)[None, :] - jnp.arange(w)[:, None]   # [W, 2W]
+    col = base + jnp.arange(nb)[:, None] * w + jnp.arange(2 * w) - (w - 1)
+    valid = ((rel >= 0) & (rel < w))[None] & (col >= 0)[:, None, :]
+    sc = jnp.where(valid[:, None], sc, NEG)
+    aw = jax.nn.softmax(sc, axis=-1)
+    out = jnp.einsum("bhij,bjhd->bihd", aw, vb)
+    return out.reshape(nb * w, heads, hd)[:s]
+
+
 def _banded_attention(q, k, v, window: int) -> jnp.ndarray:
     """Causal sliding-window attention via band gather.
 
@@ -285,20 +322,16 @@ def _tf_segment(params, x, kmem, vmem, node_mask, base, c, dev_keys,
     slice of the incumbent bias (None disables it, tracing the exact
     pre-bias program).
     Returns (logits [S, Dmax], new kmem, new vmem).  The W-wide causal
-    band is gathered from memory+segment exactly as ``_banded_attention``
-    gathers it from the full sequence, so the values are the same up to
-    the dot kernels' rounding at the segment's block shape.
-    ``attn_impl="pallas_band"`` computes the band in place through the
-    block-sparse kernel (no [S, W, heads, hd] gather copies; ``base``
-    stays a dynamic operand, so the one-compiled-program-per-segment-
-    config invariant is unchanged).
+    band over memory+segment is computed by :func:`_band_attention`: the
+    band ``_banded_attention`` gathers from the full sequence, so the
+    values are the same up to the dot kernels' rounding at the segment's
+    block shape.  ``base`` stays a dynamic operand, so every segment of
+    every graph reuses one compiled program per segment config.
+    ``attn_impl="pallas_band"`` computes the band through the
+    block-sparse kernel instead.
     """
     s, hid = x.shape
-    wm1 = kmem.shape[1]
-    w = wm1 + 1
-    hd = hid // heads
-    idx = jnp.arange(s)[:, None] + jnp.arange(w)[None, :]    # buffer index
-    valid = (base + idx - wm1) >= 0                          # global index
+    w = kmem.shape[1] + 1
     new_k, new_v = [], []
     for li, lp in enumerate(params["layers"]):
         if use_attention:
@@ -309,12 +342,8 @@ def _tf_segment(params, x, kmem, vmem, node_mask, base, c, dev_keys,
                 out = kops.band_mha_with_memory(
                     q, kbuf, vbuf, base, window=w).reshape(s, hid)
             else:
-                kb, vb = kbuf[idx], vbuf[idx]                # [S, W, heads, hd]
-                sc = jnp.einsum("nhd,nwhd->nhw", q, kb) / jnp.sqrt(
-                    jnp.float32(hd))
-                sc = jnp.where(valid[:, None, :], sc, NEG)
-                aw = jax.nn.softmax(sc, axis=-1)
-                out = jnp.einsum("nhw,nwhd->nhd", aw, vb).reshape(s, hid)
+                out = _band_attention(q, kbuf, vbuf, base, w).reshape(
+                    s, hid)
             x = x + nn.dense(lp["wo"], modulate(c, out)) * node_mask[:, None]
             new_k.append(kbuf[s:])
             new_v.append(vbuf[s:])
@@ -348,7 +377,7 @@ def apply_tf_segmented(params: Dict[str, Any], h: jnp.ndarray,
     graph of ANY length reuses one compiled step — a 50k-node GNMT never
     compiles a 50k-shaped program.  ``attn_impl="pallas_band"`` routes
     each segment's band through the block-sparse kernel (tolerance-pinned
-    parity vs the default gather in tier-1).
+    parity vs the default blocked-window band in tier-1).
 
     Equal to :func:`apply_tf` up to f32 rounding (pinned at 1e-6 by
     tests/test_segmented.py): the causal W-band each node attends to is
@@ -362,8 +391,9 @@ def apply_tf_segmented(params: Dict[str, Any], h: jnp.ndarray,
     outer jit the whole pass is one program whose size does not grow with
     the node count.  Each segment is rematerialized in the backward pass
     (``jax.checkpoint``), so a gradient holds one segment's activations
-    at a time, not the whole graph's — at the 50k-node GNMT-8 the saved
-    [S, W, heads, hd] band gathers alone would fill a 16 GB chip.
+    at a time, not the whole graph's: without it every segment's band
+    scores and softmax weights, [S, heads, 2W] per layer, would be saved
+    for the backward pass together.
     """
     n, hid = h.shape
     pad = (-n) % segment

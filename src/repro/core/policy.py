@@ -30,9 +30,10 @@ class PolicyConfig:
     use_attention: bool = True          # Fig. 3 ablation switch
     use_superposition: bool = True      # Fig. 3 ablation switch
     agg_impl: str = "jnp"               # "jnp" | "pallas" | "pallas_csr"
-    # Teacher-forced attention implementation: "jnp" (band gather; the
-    # golden-pinned default) or "pallas_band" (block-sparse band kernel —
-    # no [S, W] band copies; tolerance-pinned parity in tier-1).  Only the
+    # Teacher-forced attention implementation: "jnp" (the default: the
+    # band as blocked windows of dense products in the segmented pass, a
+    # gather in the monolithic one) or "pallas_band" (block-sparse band
+    # kernel; tolerance-pinned parity in tier-1).  Only the
     # TF paths route through it: AR sampling is inherently sequential and
     # its ring-buffer cache is already exactly band-sized (see
     # placer.sample_ar_segmented).

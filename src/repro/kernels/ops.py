@@ -216,9 +216,9 @@ def band_mha_with_memory(q: jnp.ndarray, kbuf: jnp.ndarray,
     ``[i, i + W - 1]`` (``diag_lo=0, diag_hi=W-1``); memory columns from
     before the start of time are masked by the DYNAMIC ``kv_lo =
     max(0, (W-1) - base)`` — every segment of every graph reuses ONE
-    compiled program regardless of ``base``.  Replaces the gathered
-    ``[S, W, heads, hd]`` band copies of ``placer._tf_segment``'s jnp
-    path (O(S·W) extra bytes for K and V each) with in-place band tiles.
+    compiled program regardless of ``base``.  The opt-in alternative to
+    ``placer._band_attention``, the jnp path's blocked windows of dense
+    [W, 2W] score tiles: here the band tiles are computed in place.
     """
     s, heads, hd = q.shape
     wm1 = window - 1

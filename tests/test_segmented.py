@@ -219,6 +219,104 @@ def test_tf_segmented_scan_matches_segment_loop(setup, attn_impl, masked):
         assert gap <= 1e-5, (name, gap)
 
 
+def _gather_band(q, kbuf, vbuf, base, window):
+    """The band as a gather of each query's W columns, [S, W, heads, hd]
+    copies of K and V (the oracle of ``PL._band_attention``)."""
+    s, heads, hd = q.shape
+    idx = jnp.arange(s)[:, None] + jnp.arange(window)[None, :]
+    valid = (base + idx - (window - 1)) >= 0
+    kb, vb = kbuf[idx], vbuf[idx]
+    sc = jnp.einsum("nhd,nwhd->nhw", q, kb) / jnp.sqrt(
+        jnp.asarray(hd, q.dtype))
+    sc = jnp.where(valid[:, None, :], sc, PL.NEG)
+    return jnp.einsum("nhw,nwhd->nhd", jax.nn.softmax(sc, axis=-1), vb)
+
+
+@pytest.mark.parametrize("s,window,base,kind", [
+    (64, 16, 0, "segment"),             # the first segment of a graph
+    (64, 16, 1000, "segment"),          # a segment mid-graph
+    (96, 40, 200, "segment"),           # W does not divide S
+    (24, 40, 0, "whole"),               # W > n: the monolithic band
+    (32, 16, 480, "padding"),           # a segment past the last node
+])
+def test_band_attention_matches_gather(s, window, base, kind):
+    """The blocked-window band equals the gathered band: float32 values
+    to 1e-6 and float64 gradients w.r.t. the queries, keys and values to
+    1e-5 relative, at segment starts, mid-graph, a W that does not divide
+    S, a W wider than the sequence (against the monolithic pass's own
+    gather, with W-1 zero columns before the start of time), and a
+    segment of padding (zero queries and columns after the carried
+    memory)."""
+    heads, hd = 4, 8
+    t = s if kind == "whole" else window - 1 + s
+    ks = jax.random.split(jax.random.PRNGKey(s + window + base), 4)
+    q = jax.random.normal(ks[0], (s, heads, hd))
+    kv = [jax.random.normal(k, (t, heads, hd)) for k in ks[1:3]]
+    r = jax.random.normal(ks[3], (s, heads, hd))
+    if kind == "padding":
+        q = jnp.zeros_like(q)
+        kv = [a.at[window - 1:].set(0.0) for a in kv]
+    if kind == "whole":
+        w = min(window, s)
+
+        def new(q, k, v):
+            zero = jnp.zeros((w - 1,) + k.shape[1:], k.dtype)
+            return PL._band_attention(q, jnp.concatenate([zero, k]),
+                                      jnp.concatenate([zero, v]), 0, w)
+        old = partial(PL._banded_attention, window=window)
+    else:
+        new = partial(PL._band_attention, base=jnp.int32(base),
+                      window=window)
+        old = partial(_gather_band, base=jnp.int32(base), window=window)
+    np.testing.assert_allclose(np.asarray(new(q, *kv)),
+                               np.asarray(old(q, *kv)), rtol=1e-6, atol=1e-6)
+    with jax.enable_x64(True):
+        args = [jnp.asarray(a, jnp.float64) for a in (q, *kv)]
+        r64 = jnp.asarray(r, jnp.float64)
+        g_new = jax.grad(lambda *a: (new(*a) * r64).sum(),
+                         argnums=(0, 1, 2))(*args)
+        g_old = jax.grad(lambda *a: (old(*a) * r64).sum(),
+                         argnums=(0, 1, 2))(*args)
+    for name, a, b in zip(("q", "k", "v"), g_new, g_old):
+        assert a.dtype == jnp.float64, name
+        nb = np.linalg.norm(np.asarray(b))
+        gap = np.linalg.norm(np.asarray(a) - np.asarray(b))
+        assert gap <= 1e-5 * max(nb, 1e-30), (name, gap, nb)
+
+
+def test_tf_segmented_grad_has_no_band_gather(setup):
+    """The compiled gradient of the segmented TF pass, vmapped over two
+    samples, holds no scatter and no gather of the [S·W, ...] band.  It is
+    taken w.r.t. the node embeddings and the decoder layers: the device
+    embedding's lookup (``_inputs``) lies outside the band and has a
+    scatter-add of its own, [Dmax+1, H], as its gradient."""
+    import re
+    _, gb, params = setup
+    seg, window = 24, 8
+    h = gnn.apply(params["gnn"], gb)
+    pl, _ = P.sample(params, CFG, gb, 4, jax.random.PRNGKey(6), 2)
+
+    def loss(layers, h):
+        pp = dict(params["placer"], layers=layers)
+
+        def one(p):
+            lg = PL.apply_tf_segmented(
+                pp, h, gb.node_mask, p, None, gb.mem_frac, gb.comp_frac,
+                gb.dev_feats, segment=seg, window=window, heads=CFG.heads,
+                num_devices=4)
+            return jax.nn.log_softmax(lg, axis=-1).sum()
+        return jax.vmap(one)(pl).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params["placer"]["layers"], h).compile().as_text()
+    assert "scatter(" not in text
+    gathers = [ln for ln in text.splitlines() if " gather(" in ln]
+    band = [ln for ln in gathers
+            if str(seg * window) in re.findall(r"\d+", ln.split("=")[1]
+                                                .split("gather(")[0])]
+    assert not band, band[0][:300]
+
+
 def test_logp_segmented_matches_monolithic(setup):
     """Policy-level PPO ratio path: per-node logp from the segmented TF
     pass equals the monolithic one to float tolerance on real nodes."""
