@@ -5,10 +5,10 @@ hold-out graphs with over 50k nodes (8-layer GNMT) from a policy
 pre-trained across graphs and superposition-fine-tuned per graph.  This
 campaign reproduces that axis with the segment-native pipeline:
 
-1. **Pre-train** a GDP-batch policy (segmented decode,
-   ``PolicyConfig.segment``; chunked GNN aggregation,
-   ``PolicyConfig.gnn_chunk``) on a small multi-family graph set — the
-   same compiled per-segment programs serve every graph size afterwards.
+1. **Pre-train** a GDP-batch policy (segmented decode and chunked GNN
+   aggregation, ``PolicyConfig(scale=ScaleConfig(segment=, gnn_chunk=))``)
+   on a small multi-family graph set — the same compiled per-segment
+   programs serve every graph size afterwards.
 2. **Superposition fine-tune** a per-graph fork (``ppo.clone_state``; the
    base policy is never mutated) on each held-out large graph: 8-layer
    GNMT unrolled past 50k nodes in full mode, plus deep WaveNet /
@@ -183,7 +183,7 @@ def run_jumbo(quick: bool = True, finetune_iters: int = 12,
         sc = dataclasses.replace(LARGE_SCALE,
                                  coarse_target=spec["coarse_target"],
                                  refine_window=spec["refine_window"])
-        plan = place(sh, topo, method="hierarchical", scale=sc,
+        plan = place(sh, topo, method="hierarchical",
                      pcfg=dataclasses.replace(large_policy(), scale=sc),
                      ppo=large_ppo(num_samples),
                      budget=Budget(finetune_iters=finetune_iters,

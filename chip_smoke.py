@@ -123,8 +123,7 @@ def phase1_main_path(g):
     it_log = IterLog(tr)
     tr.run_log = it_log
     t0 = time.perf_counter()
-    plan = place(g, topo, method="finetune", pcfg=pcfg, ppo=ppo,
-                 scale=LARGE_SCALE, trainer=tr,
+    plan = place(g, topo, method="finetune", pcfg=pcfg, ppo=ppo, trainer=tr,
                  budget=Budget(finetune_iters=FINETUNE_ITERS,
                                samples=SAMPLES))
     t_place = time.perf_counter() - t0
@@ -206,7 +205,7 @@ def phase2_kernels(g, p1):
     gnn_hlo = jax.jit(lambda p, b: gnn.apply(
         p, b, agg_impl="pallas_csr", scale=sc)).lower(
         params["gnn"], gb).compile().as_text()
-    s, hid, dmax = pcfg.segment, pcfg.hidden, pcfg.max_devices
+    s, hid, dmax = pcfg.scale.segment, pcfg.hidden, pcfg.max_devices
     hd = hid // pcfg.heads
     mem = jnp.zeros((pcfg.placer_layers, pcfg.window - 1, pcfg.heads, hd))
     tf_hlo = PL._tf_segment.lower(

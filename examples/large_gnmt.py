@@ -65,7 +65,7 @@ def main(full: bool = False, pretrain_iters: int = 8,
               f"{'' if bool(ok[0]) else '  (OOM -> invalid)'}")
 
     t1 = time.time()
-    zs = place(g, task.topo, pcfg=pcfg, trainer=tr, scale=pcfg.scale,
+    zs = place(g, task.topo, pcfg=pcfg, trainer=tr,
                budget=Budget(finetune_iters=0, samples=4))
     print(f"{'GDP zero-shot':>16s}: {zs.makespan:.4f}s  "
           f"({time.time()-t1:.0f}s, no weight updates)")
@@ -73,7 +73,7 @@ def main(full: bool = False, pretrain_iters: int = 8,
     t2 = time.time()
     fork = PPOTrainer(pcfg, large_ppo(num_samples=4), seed=7,
                       state=clone_state(tr.state))
-    ft = place(g, task.topo, pcfg=pcfg, trainer=fork, scale=pcfg.scale,
+    ft = place(g, task.topo, pcfg=pcfg, trainer=fork,
                budget=Budget(finetune_iters=finetune_iters, samples=4))
     print(f"{'GDP fine-tuned':>16s}: {ft.makespan:.4f}s  "
           f"(method={ft.method}, {time.time()-t2:.0f}s)")

@@ -14,10 +14,10 @@ Routing is automatic: a :class:`~repro.graphs.shards.GraphShards`
 handle, or any graph above ``ScaleConfig.hier_threshold`` nodes, goes
 hierarchical; ``budget.finetune_iters == 0`` means zero-shot (best of
 ``budget.samples`` decodes, no weight updates); everything else is the
-paper's per-graph fine-tune.  ``scale`` threads every size knob
-(segmented decode, chunked GNN, padding grid, hierarchy thresholds)
-through featurizer, policy, simulator, and the hierarchical pipeline in
-one object.
+paper's per-graph fine-tune.  The policy's ``pcfg.scale`` threads every
+size knob (segmented decode, chunked GNN, padding grid, hierarchy
+thresholds) through featurizer, policy, simulator, and the hierarchical
+pipeline in one object.
 """
 from __future__ import annotations
 
@@ -31,7 +31,6 @@ from repro.core.featurize import featurize
 from repro.core.graph import DataflowGraph
 from repro.core.policy import PolicyConfig
 from repro.core.ppo import PPOConfig, PPOTrainer
-from repro.core.scale import ScaleConfig
 from repro.graphs.shards import GraphShards, _arrays_digest
 from repro.sim.scheduler import Env, SimConfig, prepare_sim_graph
 
@@ -97,7 +96,7 @@ def _fingerprints(graph, topo) -> Dict[str, str]:
 
 
 def place(graph: Union[DataflowGraph, GraphShards], topology, *,
-          budget: Budget = Budget(), scale: Optional[ScaleConfig] = None,
+          budget: Budget = Budget(),
           sim: Optional[SimConfig] = None,
           pcfg: Optional[PolicyConfig] = None,
           ppo: Optional[PPOConfig] = None,
@@ -114,12 +113,11 @@ def place(graph: Union[DataflowGraph, GraphShards], topology, *,
     "hierarchical"); the default ``"auto"`` routes by size.
     """
     t0 = time.perf_counter()
-    sc = scale or (pcfg.scale if pcfg is not None and pcfg.scale is not None
-                   else ScaleConfig())
     sim = sim or SimConfig()
     pcfg = pcfg or dataclasses.replace(
         DEFAULT_POLICY, max_devices=max(DEFAULT_POLICY.max_devices,
-                                        topology.num_devices), scale=sc)
+                                        topology.num_devices))
+    sc = pcfg.scale
     ppo = ppo or dataclasses.replace(DEFAULT_PPO,
                                      num_samples=max(budget.samples, 2))
     n = graph.num_nodes
@@ -143,7 +141,7 @@ def place(graph: Union[DataflowGraph, GraphShards], topology, *,
     if method == "hierarchical":
         from repro.hier import place_hierarchical
         res = place_hierarchical(
-            graph, topology, pcfg=pcfg, ppo=ppo, sim=sim, scale=sc,
+            graph, topology, pcfg=pcfg, ppo=ppo, sim=sim,
             iterations=budget.finetune_iters, num_samples=budget.samples,
             seed=budget.seed, trainer=trainer,
             max_windows=budget.refine_windows, log_every=log_every)

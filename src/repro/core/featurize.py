@@ -28,6 +28,7 @@ import numpy as np
 import jax.numpy as jnp
 
 from repro.core.graph import DataflowGraph, MAX_SHAPE_RANK
+from repro.core.scale import ScaleConfig
 
 NUM_NUMERIC_FEATURES = 6 + MAX_SHAPE_RANK
 NUM_DEVICE_FEATURES = 6
@@ -46,8 +47,8 @@ class GraphBatch(NamedTuple):
     dev_mem_cap: jnp.ndarray  # f32[D] device cap / tightest cap (mem_frac units)
     num_nodes: int           # real node count (static python int)
     # BSR adjacency index for the CSR-blocked aggregation kernel
-    # (kernels.csr_maxpool.BlockIndex), or None.  Built by
-    # ``featurize(..., csr=True)``; ``pad_to_common`` drops it (re-padding
+    # (kernels.csr_maxpool.BlockIndex), or None.  Built by ``featurize``
+    # when ``scale.csr`` is set; ``pad_to_common`` drops it (re-padding
     # invalidates the tile geometry — re-featurize to rebuild).
     csr_blocks: Optional[Any] = None
 
@@ -75,9 +76,8 @@ def device_features(topo) -> np.ndarray:
 
 def featurize(g: DataflowGraph, max_deg: int = 8,
               pad_to: Optional[int] = None, topo=None,
-              pad_multiple: Optional[int] = None, csr: bool = False,
               csr_block_n: int = 64, csr_block_m: int = 128,
-              scale=None) -> GraphBatch:
+              scale: ScaleConfig = ScaleConfig()) -> GraphBatch:
     """``topo`` (sim.device.Topology) enables the resource-aware decoder
     context: per-node memory/compute fractions the AR placer accumulates
     per device while decoding, plus the per-device capability table
@@ -88,20 +88,12 @@ def featurize(g: DataflowGraph, max_deg: int = 8,
     has one compiled shape) and ``scale.csr`` (build the BSR adjacency
     block index, O(edges) numpy work done once per graph, so the GNN can
     aggregate via the CSR-blocked kernel,
-    ``PolicyConfig.agg_impl="pallas_csr"``).  ``pad_multiple=``/``csr=``
-    are the deprecated keyword aliases for those two — passing either
-    without ``scale`` warns and keeps working for one release."""
-    if scale is not None:
-        pad_multiple, csr = scale.pad_multiple, scale.csr
-    elif pad_multiple is not None or csr:
-        from repro.core.scale import warn_deprecated_alias
-        warn_deprecated_alias(
-            "featurize", "pad_multiple" if pad_multiple is not None
-            else "csr")
+    ``PolicyConfig.agg_impl="pallas_csr"``)."""
     n = g.num_nodes
     pad_n = pad_to or n
-    if pad_multiple:
-        pad_n = ((pad_n + pad_multiple - 1) // pad_multiple) * pad_multiple
+    if scale.pad_multiple:
+        m = scale.pad_multiple
+        pad_n = ((pad_n + m - 1) // m) * m
     assert pad_n >= n, (pad_n, n)
 
     f = np.zeros((pad_n, NUM_NUMERIC_FEATURES), np.float32)
@@ -148,7 +140,7 @@ def featurize(g: DataflowGraph, max_deg: int = 8,
         # masked decode, PolicyConfig.mask_full_devices)
         dev_mem_cap = (caps / tight).astype(np.float32)
     blocks = None
-    if csr:
+    if scale.csr:
         from repro.kernels.csr_maxpool import build_block_index
         blocks = build_block_index(nbr_idx, nbr_mask, pad_n,
                                    block_n=csr_block_n, block_m=csr_block_m)
@@ -197,7 +189,8 @@ def _window_neighbors(edges, lo: int, hi: int, k: int, pad_n: int
 
 def featurize_window(shards, lo: int = 0, hi: Optional[int] = None,
                      max_deg: int = 8, pad_to: Optional[int] = None,
-                     topo=None, scale=None) -> GraphBatch:
+                     topo=None,
+                     scale: ScaleConfig = ScaleConfig()) -> GraphBatch:
     """Out-of-core :func:`featurize`: one window ``[lo, hi)`` of a
     sharded graph (:class:`repro.graphs.shards.GraphShards`), without
     ever materializing whole-graph feature/neighbor arrays.
@@ -221,7 +214,7 @@ def featurize_window(shards, lo: int = 0, hi: Optional[int] = None,
     assert 0 <= lo <= hi <= n_all, (lo, hi, n_all)
     n = hi - lo
     pad_n = pad_to or n
-    if scale is not None and scale.pad_multiple:
+    if scale.pad_multiple:
         m = scale.pad_multiple
         pad_n = ((pad_n + m - 1) // m) * m
     assert pad_n >= n, (pad_n, n)
@@ -298,7 +291,7 @@ def jumbo_bucket(n: int, multiple: int = 2048) -> int:
     Past the ladder, power-of-two buckets waste up to ~50% padding on a
     50k-node graph; a segmented decoder only needs the node dim to be a
     multiple of its segment, so the serving tier pads jumbo admissions to
-    this much tighter grid instead (``ServeConfig.jumbo_pad_multiple``).
+    this much tighter grid instead (``ScaleConfig.jumbo_pad_multiple``).
     """
     return ((n + multiple - 1) // multiple) * multiple
 

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from repro.core import nn
 from repro.core.featurize import GraphBatch, NUM_NUMERIC_FEATURES
 from repro.core.graph import NUM_OP_TYPES
+from repro.core.scale import ScaleConfig
 from repro.obs import jaxprof
 
 NEG = -1e9
@@ -66,8 +67,8 @@ def _neighbor_max(z: jnp.ndarray, nbr_idx: jnp.ndarray, nbr_mask: jnp.ndarray,
 
     ``agg_impl="pallas_csr"`` streams only the non-empty adjacency tiles
     via the BSR index carried on the GraphBatch (``csr_blocks``; built by
-    ``featurize(..., csr=True)``) — bytes touched scale with the edges,
-    not with chunk·N.
+    ``featurize`` when ``scale.csr`` is set) — bytes touched scale with
+    the edges, not with chunk·N.
     """
     if agg_impl == "pallas":
         from repro.kernels import ops as kops
@@ -76,7 +77,7 @@ def _neighbor_max(z: jnp.ndarray, nbr_idx: jnp.ndarray, nbr_mask: jnp.ndarray,
         if csr_blocks is None:
             raise ValueError(
                 "agg_impl='pallas_csr' needs a GraphBatch featurized with "
-                "csr=True (GraphBatch.csr_blocks is None)")
+                "scale.csr=True (GraphBatch.csr_blocks is None)")
         from repro.kernels import ops as kops
         return kops.neighbor_maxpool_csr(z, csr_blocks,
                                          num_rows=z.shape[0])
@@ -105,25 +106,19 @@ jaxprof.register("gnn.chunked_gather_max", _chunked_gather_max)
 
 
 def apply(params: Dict[str, Any], gb: GraphBatch, *, agg_impl: str = "jnp",
-          chunk: Optional[int] = None, scale=None) -> jnp.ndarray:
+          scale: ScaleConfig = ScaleConfig()) -> jnp.ndarray:
     """Returns node embeddings f32[N, H].
 
     ``scale`` (:class:`repro.core.scale.ScaleConfig`) supplies the
     chunked-gather bound (``scale.gnn_chunk``: peak memory O(chunk·K·H),
-    bit-identical results).  ``chunk=`` is the deprecated alias for it —
-    passing it without ``scale`` warns and keeps working for one
-    release."""
-    if scale is not None:
-        chunk = scale.gnn_chunk
-    elif chunk is not None:
-        from repro.core.scale import warn_deprecated_alias
-        warn_deprecated_alias("gnn.apply", "chunk")
+    bit-identical results)."""
     x = jnp.concatenate([params["op_emb"][gb.op], gb.feats], axis=-1)
     h = jax.nn.relu(nn.dense(params["in"], x))
     h = h * gb.node_mask[:, None]
     for lp in params["layers"]:
         z = jax.nn.sigmoid(nn.dense(lp["agg"], h))          # Eq. (2) affine+sigma
-        agg = _neighbor_max(z, gb.nbr_idx, gb.nbr_mask, agg_impl, chunk,
+        agg = _neighbor_max(z, gb.nbr_idx, gb.nbr_mask, agg_impl,
+                            scale.gnn_chunk,
                             getattr(gb, "csr_blocks", None))
         h = jax.nn.relu(nn.dense(lp["upd"], jnp.concatenate([h, agg], -1)))
         h = h * gb.node_mask[:, None]

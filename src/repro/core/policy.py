@@ -14,7 +14,7 @@ import jax.numpy as jnp
 
 from repro.core import gnn, placer, superposition
 from repro.core.featurize import GraphBatch
-from repro.core.scale import ScaleConfig, warn_deprecated_alias
+from repro.core.scale import ScaleConfig
 
 
 @dataclasses.dataclass(frozen=True)
@@ -38,15 +38,6 @@ class PolicyConfig:
     # its ring-buffer cache is already exactly band-sized (see
     # placer.sample_ar_segmented).
     attn_impl: str = "jnp"
-    # DEPRECATED alias for ``scale.segment`` (segmented decode: fixed-size
-    # segments with carried Transformer-XL-style state; None = monolithic,
-    # bit-identical — pinned by tests/test_segmented.py).  Constructing
-    # with ``segment=`` and no ``scale`` warns and synthesizes a
-    # ScaleConfig; reads of ``cfg.segment`` stay canonical either way.
-    segment: Optional[int] = None
-    # DEPRECATED alias for ``scale.gnn_chunk`` (chunked GNN neighbor
-    # aggregation: the [chunk, K, H] gather peaks at O(chunk), not O(N)).
-    gnn_chunk: Optional[int] = None
     # Memory-aware decode: mask devices a node would push past their
     # memory cap (the decoder's running per-device accumulators vs
     # featurize's dev_mem_cap), so sampled placements are feasible by
@@ -56,30 +47,12 @@ class PolicyConfig:
     # it on (at 50k nodes an unconstrained policy fork can spend its
     # whole fine-tune budget before drawing one valid sample).
     mask_full_devices: bool = False
-    # The consolidated scale knobs (segmented decode, chunked GNN gather,
-    # padding grid, hierarchy thresholds — see repro.core.scale).  When
-    # set it is authoritative: the legacy ``segment``/``gnn_chunk``
-    # fields are synced from it so every internal reader keeps working.
-    scale: Optional[ScaleConfig] = None
-
-    def __post_init__(self):
-        if self.scale is not None:
-            for alias, new in (("segment", self.scale.segment),
-                               ("gnn_chunk", self.scale.gnn_chunk)):
-                old = getattr(self, alias)
-                if old is not None and old != new:
-                    raise ValueError(
-                        f"PolicyConfig({alias}={old}) conflicts with "
-                        f"scale.{alias}={new}; set the value on "
-                        f"ScaleConfig only")
-            object.__setattr__(self, "segment", self.scale.segment)
-            object.__setattr__(self, "gnn_chunk", self.scale.gnn_chunk)
-        elif self.segment is not None or self.gnn_chunk is not None:
-            for alias in ("segment", "gnn_chunk"):
-                if getattr(self, alias) is not None:
-                    warn_deprecated_alias("PolicyConfig", alias)
-            object.__setattr__(self, "scale", ScaleConfig(
-                segment=self.segment, gnn_chunk=self.gnn_chunk))
+    # Every graph-size knob (segmented decode, chunked GNN gather,
+    # padding grid, jumbo bucket, hierarchy thresholds): the one place
+    # they are set — see repro.core.scale.  ``scale.segment`` None is the
+    # monolithic decode, bit-identical to the segmented one (pinned by
+    # tests/test_segmented.py).
+    scale: ScaleConfig = ScaleConfig()
 
 
 def init(key, cfg: PolicyConfig) -> Dict[str, Any]:
@@ -94,7 +67,7 @@ def init(key, cfg: PolicyConfig) -> Dict[str, Any]:
 
 def _decode_fn(cfg: PolicyConfig, gb: GraphBatch, num_devices: int):
     """(placer decode fn, shared kwargs) for the config: the segmented
-    variant plus ``segment=`` when ``cfg.segment`` is set, monolithic
+    variant plus ``segment=`` when ``cfg.scale.segment`` is set, monolithic
     otherwise.  One spot assembles the decode kwargs so the sampling,
     ratio, and greedy paths can never drift apart."""
     kwargs = dict(window=cfg.window, heads=cfg.heads,
@@ -103,9 +76,9 @@ def _decode_fn(cfg: PolicyConfig, gb: GraphBatch, num_devices: int):
                   dev_mem_cap=(gb.dev_mem_cap if cfg.mask_full_devices
                                else None),
                   mask_full=cfg.mask_full_devices)
-    if cfg.segment is not None:
+    if cfg.scale.segment is not None:
         return placer.sample_ar_segmented, dict(kwargs,
-                                                segment=cfg.segment)
+                                                segment=cfg.scale.segment)
     return placer.sample_ar, kwargs
 
 
@@ -134,8 +107,7 @@ def incumbent_bias(cfg: PolicyConfig, gb: GraphBatch,
 
 
 def _embed(params, cfg: PolicyConfig, gb: GraphBatch):
-    h = gnn.apply(params["gnn"], gb, agg_impl=cfg.agg_impl,
-                  scale=cfg.scale or ScaleConfig())
+    h = gnn.apply(params["gnn"], gb, agg_impl=cfg.agg_impl, scale=cfg.scale)
     c = None
     if cfg.use_superposition:
         x0 = gnn.graph_summary(h, gb.node_mask)
@@ -149,7 +121,7 @@ def sample(params, cfg: PolicyConfig, gb: GraphBatch, num_devices: int,
            ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Returns (placements i32[M, N], per-node logp f32[M, N]).
 
-    With ``cfg.segment`` set the AR decode runs segment-by-segment
+    With ``cfg.scale.segment`` set the AR decode runs segment-by-segment
     (callers must NOT wrap this in an outer jit — the segmented path
     manages its own per-segment compiled programs).
 
@@ -209,7 +181,7 @@ def logp_and_entropy(params, cfg: PolicyConfig, gb: GraphBatch,
     # kernelize), so it joins here rather than in _decode_fn
     kwargs = dict(_decode_fn(cfg, gb, num_devices)[1],
                   attn_impl=cfg.attn_impl)
-    tf_fn = (placer.apply_tf_segmented if cfg.segment is not None
+    tf_fn = (placer.apply_tf_segmented if cfg.scale.segment is not None
              else placer.apply_tf)
     bias = incumbent_bias(cfg, gb, incumbent, migration_bias)
 

@@ -176,7 +176,7 @@ jaxprof.register("ppo.logp", _logp)
 # is a Python loop over per-segment compiled scans, and an outer jit would
 # trace that loop into one graph-sized XLA program.
 def _sample_any(params, pcfg, gb, num_devices, key, num_samples):
-    if pcfg.segment is None:
+    if pcfg.scale.segment is None:
         return _sample(params, pcfg, gb, num_devices, key, num_samples)
     return policy_mod.sample(params, pcfg, gb, num_devices, key, num_samples)
 

@@ -1,20 +1,11 @@
 """One home for every graph-size knob: :class:`ScaleConfig`.
 
-Historically the knobs that let the pipeline scale past toy graphs were
-scattered across the layers that consume them — ``PolicyConfig.segment``
-(segmented decode), ``PolicyConfig.gnn_chunk`` (chunked GNN gather),
-``featurize(pad_multiple=/csr=)`` (padding grid / BSR adjacency) and
-``ServeConfig.jumbo_threshold``/``jumbo_pad_multiple`` (serving-tier
-jumbo bucket).  Scaling a campaign meant threading four keyword sets
-through three configs and keeping them mutually consistent by hand.
-
-:class:`ScaleConfig` consolidates them.  ``PolicyConfig(scale=...)``,
-``ServeConfig(scale=...)``, ``featurize(..., scale=...)`` and
-``gnn.apply(..., scale=...)`` all read from one frozen dataclass; the
-old keywords still work for one release as deprecated aliases (they
-raise a loud ``DeprecationWarning`` and are folded into a synthesized
-``ScaleConfig``), so existing pins and scripts keep their exact
-behavior while migrating.
+Segmented decode, the chunked GNN gather, the featurizer's padding grid,
+the serving tier's jumbo bucket and the hierarchy thresholds all bound
+compiled shapes or peak memory by graph size.  They live in one frozen
+dataclass, set once as ``PolicyConfig(scale=...)``; every layer reads
+them from the policy's config (``featurize`` and ``gnn.apply`` take the
+same object as ``scale=``).
 
 The hierarchical coarsen→place→refine pipeline (``repro.hier``) adds
 its own knobs here too — ``hier_threshold`` is where ``repro.api.place``
@@ -25,12 +16,7 @@ streamed refinement windows.
 from __future__ import annotations
 
 import dataclasses
-import warnings
 from typing import Optional
-
-# Aliases removal target, referenced by the deprecation messages so the
-# warning says when the old keywords go away.
-_ALIAS_REMOVAL = "the next release"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -78,13 +64,3 @@ class ScaleConfig:
             return self
         return dataclasses.replace(self, pad_multiple=self.segment)
 
-
-def warn_deprecated_alias(owner: str, alias: str) -> None:
-    """Emit the one loud ``DeprecationWarning`` every legacy scale
-    keyword funnels through (``stacklevel`` points at the caller of the
-    deprecated API, not at this helper)."""
-    warnings.warn(
-        f"{owner}({alias}=...) is deprecated and will be removed in "
-        f"{_ALIAS_REMOVAL}; pass scale=ScaleConfig({alias}=...) instead "
-        f"(see docs/scaling.md).",
-        DeprecationWarning, stacklevel=3)

@@ -32,7 +32,6 @@ from repro.core import baselines as B
 from repro.core.featurize import featurize
 from repro.core.graph import DataflowGraph
 from repro.core.ppo import PPOConfig, PPOTrainer
-from repro.core.scale import ScaleConfig
 from repro.graphs.shards import GraphShards
 from repro.hier.coarsen import Coarsening, coarsen
 from repro.hier.refine import RefineResult, refine
@@ -59,7 +58,6 @@ class HierResult:
 def place_hierarchical(source: Union[DataflowGraph, GraphShards], topo, *,
                        pcfg, ppo: Optional[PPOConfig] = None,
                        sim: Optional[SimConfig] = None,
-                       scale: Optional[ScaleConfig] = None,
                        iterations: int = 40, num_samples: int = 8,
                        seed: int = 0, trainer: Optional[PPOTrainer] = None,
                        max_windows: Optional[int] = None,
@@ -70,11 +68,11 @@ def place_hierarchical(source: Union[DataflowGraph, GraphShards], topo, *,
     ``trainer`` (optional) continues from pre-trained weights instead of
     a fresh ``PPOTrainer(pcfg, ppo, seed)`` — the superposition network
     makes coarse graphs just another graph distribution, so zero-shot +
-    short fine-tune works the same as at normal scale.  ``scale``
+    short fine-tune works the same as at normal scale.  ``pcfg.scale``
     supplies ``coarse_target`` (supernode count) and ``refine_window``.
     """
     t0 = time.perf_counter()
-    sc = scale or (getattr(pcfg, "scale", None) or ScaleConfig())
+    sc = pcfg.scale
     sim = sim or SimConfig()
     ppo = ppo or PPOConfig(num_samples=num_samples)
     d = topo.num_devices
@@ -103,7 +101,7 @@ def place_hierarchical(source: Union[DataflowGraph, GraphShards], topo, *,
     key = jax.random.PRNGKey(seed + 7)
     rr = refine(tr.state.params, pcfg, fenv, source, topo, lifted, key=key,
                 window=sc.refine_window, num_samples=max(num_samples, 2),
-                scale=sc, max_windows=max_windows, log_every=log_every)
+                max_windows=max_windows, log_every=log_every)
     _, _, valid = fenv.rewards(rr.placement[None])
     return HierResult(placement=rr.placement, makespan=rr.makespan,
                       valid=bool(np.asarray(valid)[0]),

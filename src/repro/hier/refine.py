@@ -68,7 +68,7 @@ def refine(params, cfg, env, source: Union[DataflowGraph, GraphShards],
            topo, current: np.ndarray, *, key,
            window: int = 8192, num_samples: int = 4,
            migration_bias: float = 2.0, temperature: float = 1.0,
-           scale=None, max_windows: Optional[int] = None,
+           max_windows: Optional[int] = None,
            log_every: int = 0) -> RefineResult:
     """One streaming refinement sweep over ``source``.
 
@@ -100,7 +100,7 @@ def refine(params, cfg, env, source: Union[DataflowGraph, GraphShards],
     usage = np.bincount(current, weights=mem, minlength=d)[:d]
     for i in range(num_windows):
         lo, hi = i * window, min((i + 1) * window, n)
-        gb = _window_batch(source, lo, hi, topo, window, scale)
+        gb = _window_batch(source, lo, hi, topo, window, cfg.scale)
         win_usage = np.bincount(current[lo:hi], weights=mem[lo:hi],
                                 minlength=d)[:d]
         outside = usage - win_usage

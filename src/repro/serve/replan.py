@@ -44,7 +44,6 @@ from repro.core import policy
 from repro.core.featurize import featurize
 from repro.core.graph import DataflowGraph
 from repro.core.policy import PolicyConfig
-from repro.core.scale import ScaleConfig
 from repro.sim.chaos import alive_devices, migration_bytes
 from repro.sim.device import Topology
 from repro.sim.scheduler import Env, SimConfig, prepare_sim_graph
@@ -136,9 +135,9 @@ def replan(params, cfg: PolicyConfig, g: DataflowGraph, topo: Topology,
     # decode must not emit dead devices: force the memory-aware mask on
     # (dev_mem_cap is 0 for failed devices, so they are closed).
     pcfg = dataclasses.replace(cfg, mask_full_devices=True)
-    seg = cfg.segment
+    seg = cfg.scale.segment
     gb = featurize(g, topo=topo,
-                   scale=ScaleConfig(pad_multiple=seg))
+                   scale=dataclasses.replace(cfg.scale, pad_multiple=seg))
 
     # nodes whose device died must be restored anyway (forced bytes) —
     # they carry no stay-put preference.

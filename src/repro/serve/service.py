@@ -62,7 +62,6 @@ from repro.core import policy as policy_mod
 from repro.core.featurize import bucket_size, featurize, jumbo_bucket
 from repro.core.policy import PolicyConfig
 from repro.core.ppo import PPOTrainer, clone_state
-from repro.core.scale import ScaleConfig, warn_deprecated_alias
 from repro.obs import jaxprof
 from repro.obs.metrics import CounterDict, Histogram, MetricsRegistry
 from repro.obs.trace import get_tracer
@@ -154,53 +153,18 @@ class ServeConfig:
     jittered_bandwidth: bool = False
     jitter_amp: float = 0.25
     jitter_seed: int = 0
-    # Jumbo bucket (paper-scale admissions): graphs above
-    # ``jumbo_threshold`` nodes skip the micro-batcher — they are padded
-    # to the next multiple of ``jumbo_pad_multiple`` (featurize.
-    # jumbo_bucket; far tighter than the power-of-two ladder at 50k
-    # nodes) and served one at a time through the segmented decode when
-    # the policy has one (``PolicyConfig.segment``).  Graphs above
+    # Jumbo bucket (paper-scale admissions): graphs above the policy's
+    # ``scale.jumbo_threshold`` nodes skip the micro-batcher — they are
+    # padded to the next multiple of ``scale.jumbo_pad_multiple``
+    # (featurize.jumbo_bucket; far tighter than the power-of-two ladder
+    # at 50k nodes) and served one at a time through the segmented
+    # decode when the policy has one (``scale.segment``).  Graphs above
     # ``max_graph_nodes`` — or topologies wider than the policy head —
     # are REJECTED: a typed shed to the degraded baseline fast path
     # (``Request.rejection``, ``counts["shed_rejected"]``) instead of an
     # assert crashing the worker.
-    #
-    # ``jumbo_threshold``/``jumbo_pad_multiple`` are DEPRECATED aliases
-    # for the same fields on ``scale`` (repro.core.scale.ScaleConfig);
-    # passing either without ``scale`` warns and keeps working for one
-    # release.  After construction both fields always hold the resolved
-    # values, whichever spelling configured them.
-    jumbo_threshold: Optional[int] = None
-    jumbo_pad_multiple: Optional[int] = None
     max_graph_nodes: int = 1 << 17
-    scale: Optional[ScaleConfig] = None
     costs: ServiceCosts = dataclasses.field(default_factory=ServiceCosts)
-
-    def __post_init__(self):
-        scale = self.scale
-        if scale is not None:
-            for alias in ("jumbo_threshold", "jumbo_pad_multiple"):
-                old, new = getattr(self, alias), getattr(scale, alias)
-                if old is not None and old != new:
-                    raise ValueError(
-                        f"ServeConfig({alias}={old}) conflicts with "
-                        f"scale.{alias}={new}; set the value on "
-                        f"ScaleConfig only")
-        else:
-            for alias in ("jumbo_threshold", "jumbo_pad_multiple"):
-                if getattr(self, alias) is not None:
-                    warn_deprecated_alias("ServeConfig", alias)
-            scale = ScaleConfig(
-                jumbo_threshold=(self.jumbo_threshold
-                                 if self.jumbo_threshold is not None
-                                 else ScaleConfig.jumbo_threshold),
-                jumbo_pad_multiple=(self.jumbo_pad_multiple
-                                    if self.jumbo_pad_multiple is not None
-                                    else ScaleConfig.jumbo_pad_multiple))
-            object.__setattr__(self, "scale", scale)
-        object.__setattr__(self, "jumbo_threshold", scale.jumbo_threshold)
-        object.__setattr__(self, "jumbo_pad_multiple",
-                           scale.jumbo_pad_multiple)
 
     @property
     def sim(self) -> SimConfig:
@@ -477,7 +441,7 @@ class PlacementService:
                 return req
         self._inflight[key] = []
         ctx = self._context(key, g, topo, order)
-        if g.num_nodes > self.cfg.jumbo_threshold:
+        if g.num_nodes > self.pcfg.scale.jumbo_threshold:
             # jumbo bucket: segment-padded, served solo — batching would
             # backfill max_batch copies of a 50k-node graph for nothing
             self._serve_jumbo(req, ctx)
@@ -588,15 +552,15 @@ class PlacementService:
         # graphs pad to the segment-aligned jumbo bucket instead of the
         # power-of-two ladder (tighter, and divisible by the decoder's
         # segment when one is configured).
-        if g.num_nodes > self.cfg.jumbo_threshold:
-            mult = self.cfg.jumbo_pad_multiple
-            if self.pcfg.segment:
-                mult = max(mult // self.pcfg.segment, 1) * self.pcfg.segment
+        sc = self.pcfg.scale
+        if g.num_nodes > sc.jumbo_threshold:
+            mult = sc.jumbo_pad_multiple
+            if sc.segment:
+                mult = max(mult // sc.segment, 1) * sc.segment
             pad_n = jumbo_bucket(g.num_nodes, mult)
         else:
             pad_n = bucket_size(g.num_nodes)
-        seg = (self.pcfg.segment if self.pcfg.segment and
-               pad_n % self.pcfg.segment == 0 else None)
+        seg = sc.segment if sc.segment and pad_n % sc.segment == 0 else None
         span = partial(tracer.span, cat="serve", clock=self.clock,
                        tid=self.tid)
         with span("serve.sim_graph"):
@@ -651,7 +615,7 @@ class PlacementService:
                 # programs — wrapping the Python segment loop in the outer
                 # jit would trace it into one graph-sized program
                 sample_fn = (policy_mod.sample_batch
-                             if self.pcfg.segment is not None
+                             if self.pcfg.scale.segment is not None
                              else _sample_batch_jit)
                 placements, _ = sample_fn(
                     self.trainer.state.params, self.pcfg, fl.sgb, fl.key[1],

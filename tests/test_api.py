@@ -1,21 +1,24 @@
 """repro.api.place: one front door, three routes, stable plan contract.
 
-Also pins the ScaleConfig consolidation: the legacy per-config keywords
-keep working as loud DeprecationWarning aliases, conflicts fail fast,
-and ``with_segment_padding`` keeps featurizer and simulator on the same
-padding grid.
+Also pins the ScaleConfig consolidation: ``PolicyConfig.scale`` is the
+one place a graph-size knob is set (the old per-config keywords are
+rejected), and ``with_segment_padding`` keeps featurizer and simulator
+on the same padding grid.
 """
 import dataclasses
-import warnings
 
+import jax
 import numpy as np
 import pytest
 
 from repro.api import Budget, PlacementPlan, place
+from repro.core import gnn
+from repro.core.featurize import featurize
 from repro.core.policy import PolicyConfig
 from repro.core.scale import ScaleConfig
 from repro.graphs import synthetic as S
 from repro.graphs.shards import write_shards
+from repro.serve.service import ServeConfig
 from repro.sim import p100_topology
 
 SMALL = PolicyConfig(hidden=16, gnn_layers=1, op_emb=8, placer_layers=1,
@@ -60,7 +63,8 @@ def test_place_zero_shot_route():
 def test_place_hierarchical_forced_and_by_threshold():
     g, topo = _setup()
     sc = ScaleConfig(coarse_target=24, refine_window=64)
-    plan = place(g, topo, pcfg=SMALL, scale=sc, method="hierarchical",
+    plan = place(g, topo, pcfg=dataclasses.replace(SMALL, scale=sc),
+                 method="hierarchical",
                  budget=Budget(finetune_iters=2, samples=2))
     _check_plan(plan, g, topo, "hierarchical")
     assert "coarse" in plan.fingerprints
@@ -68,8 +72,8 @@ def test_place_hierarchical_forced_and_by_threshold():
     # facade)
     assert plan.makespan <= plan.trajectory[0]
     # auto-routing: a graph above hier_threshold goes hierarchical
-    auto = place(g, topo, pcfg=SMALL,
-                 scale=dataclasses.replace(sc, hier_threshold=16),
+    auto = place(g, topo, pcfg=dataclasses.replace(
+                     SMALL, scale=dataclasses.replace(sc, hier_threshold=16)),
                  budget=Budget(finetune_iters=2, samples=2))
     assert auto.method == "hierarchical"
 
@@ -78,7 +82,7 @@ def test_place_shards_route_hierarchical(tmp_path):
     g, topo = _setup()
     sh = write_shards(g, str(tmp_path / "sh"), shard_nodes=64)
     sc = ScaleConfig(coarse_target=24, refine_window=64)
-    plan = place(sh, topo, pcfg=SMALL, scale=sc,
+    plan = place(sh, topo, pcfg=dataclasses.replace(SMALL, scale=sc),
                  budget=Budget(finetune_iters=2, samples=2,
                                refine_windows=1))
     _check_plan(plan, sh.load_graph(), topo, "hierarchical")
@@ -92,34 +96,26 @@ def test_place_unknown_method_raises():
 
 
 # ---------------------------------------------------------------------------
-# ScaleConfig consolidation: deprecated aliases
+# ScaleConfig consolidation: one spelling
 # ---------------------------------------------------------------------------
-def test_policy_config_legacy_aliases_warn_and_sync():
-    with pytest.warns(DeprecationWarning, match="PolicyConfig.*segment"):
-        cfg = PolicyConfig(segment=8)
-    assert cfg.scale == ScaleConfig(segment=8)
-    assert cfg.segment == 8
-    with pytest.warns(DeprecationWarning, match="gnn_chunk"):
-        cfg = PolicyConfig(gnn_chunk=32)
-    assert cfg.scale.gnn_chunk == 32
+def _gnn_apply_chunk():
+    gb = featurize(S.rnnlm(2, time_steps=3))
+    gnn.apply(gnn.init(jax.random.PRNGKey(0), 16, 1), gb, chunk=64)
 
 
-def test_policy_config_scale_is_authoritative():
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")   # no warning on the new spelling
-        cfg = PolicyConfig(scale=ScaleConfig(segment=8, gnn_chunk=32))
-    assert cfg.segment == 8 and cfg.gnn_chunk == 32
-    with pytest.raises(ValueError, match="conflicts with"):
-        PolicyConfig(segment=4, scale=ScaleConfig(segment=8))
-
-
-def test_serve_config_legacy_aliases_warn_and_sync():
-    from repro.serve.service import ServeConfig
-    with pytest.warns(DeprecationWarning, match="ServeConfig.*jumbo"):
-        cfg = ServeConfig(jumbo_threshold=123)
-    assert cfg.scale.jumbo_threshold == 123
-    with pytest.raises(ValueError, match="conflicts with"):
-        ServeConfig(jumbo_threshold=1, scale=ScaleConfig(jumbo_threshold=2))
+@pytest.mark.parametrize("call", [
+    lambda: PolicyConfig(segment=8),
+    lambda: PolicyConfig(gnn_chunk=32),
+    lambda: ServeConfig(jumbo_threshold=64),
+    lambda: ServeConfig(scale=ScaleConfig()),
+    lambda: featurize(S.rnnlm(2, time_steps=3), pad_multiple=64),
+    _gnn_apply_chunk,
+], ids=["PolicyConfig.segment", "PolicyConfig.gnn_chunk",
+        "ServeConfig.jumbo_threshold", "ServeConfig.scale",
+        "featurize.pad_multiple", "gnn.apply.chunk"])
+def test_removed_scale_keywords_are_rejected(call):
+    with pytest.raises(TypeError, match="unexpected keyword"):
+        call()
 
 
 def test_with_segment_padding():

@@ -18,6 +18,7 @@ from hypothesis import given, settings, strategies as st
 from repro.core import baselines as B
 from repro.core.policy import PolicyConfig
 from repro.core.ppo import PPOConfig, PPOTrainer
+from repro.core.scale import ScaleConfig
 from repro.graphs import synthetic as S
 from repro.serve import fingerprint as FP
 from repro.serve.cluster import ClusterConfig, PlacementCluster
@@ -201,7 +202,7 @@ def test_recovery_trajectory_segmented_matches_monolithic():
     rcfg = ReplanConfig(num_samples=4, seed=1)
     init = replan(params, PCFG, g, topo, B.round_robin(g, topo), (),
                   rcfg=dataclasses.replace(rcfg, scratch_only=True)).placement
-    seg_cfg = dataclasses.replace(PCFG, segment=16)
+    seg_cfg = dataclasses.replace(PCFG, scale=ScaleConfig(segment=16))
     mono = X.recovery_trajectory(
         g, topo, _schedule(), init, make_replace_fn(params, PCFG, rcfg=rcfg))
     seg = X.recovery_trajectory(

@@ -165,7 +165,8 @@ def test_place_hierarchical_end_to_end(tmp_path):
     g = _graph()
     topo = _topo(g)
     sc = ScaleConfig(coarse_target=24, refine_window=64)
-    res = place_hierarchical(g, topo, pcfg=SMALL, scale=sc,
+    pcfg = dataclasses.replace(SMALL, scale=sc)
+    res = place_hierarchical(g, topo, pcfg=pcfg,
                              iterations=2, num_samples=2, seed=0,
                              log_every=0)
     assert res.valid
@@ -178,7 +179,7 @@ def test_place_hierarchical_end_to_end(tmp_path):
     assert len(res.coarsening.fingerprint) == 64
     # shard-backed source takes the same pipeline to the same contract
     sh = write_shards(g, str(tmp_path / "sh"), shard_nodes=64)
-    res2 = place_hierarchical(sh, topo, pcfg=SMALL, scale=sc,
+    res2 = place_hierarchical(sh, topo, pcfg=pcfg,
                               iterations=2, num_samples=2, seed=0,
                               log_every=0)
     assert res2.valid and res2.makespan <= res2.trajectory[0]

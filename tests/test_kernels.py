@@ -13,6 +13,7 @@ from repro.kernels import ref as R
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.segment_maxpool import neighbor_maxpool_dense
 from repro.kernels import ops
+from repro.core.scale import ScaleConfig
 
 RNG = np.random.RandomState(0)
 
@@ -319,7 +320,7 @@ def test_gnn_pallas_csr_matches_jnp():
     from repro.core.featurize import featurize
     from repro.graphs import synthetic as S
     g = S.rnnlm(2, time_steps=3)
-    gb = featurize(g, max_deg=8, csr=True)
+    gb = featurize(g, max_deg=8, scale=ScaleConfig(csr=True))
     assert gb.csr_blocks is not None
     params = gnn.init(jax.random.PRNGKey(0), 32, 2)
     h_jnp = gnn.apply(params, gb, agg_impl="jnp")
@@ -329,7 +330,7 @@ def test_gnn_pallas_csr_matches_jnp():
 
 
 def test_gnn_pallas_csr_requires_block_index():
-    """agg_impl='pallas_csr' without a featurize(csr=True) batch is a
+    """agg_impl='pallas_csr' without a scale.csr=True batch is a
     loud config error, not a silent fallback."""
     from repro.core import gnn
     from repro.core.featurize import featurize
@@ -357,9 +358,10 @@ def test_policy_kernel_impls_logp_parity(fleet, segment):
     topo = (p100_topology(4).with_mem_caps(g.total_mem())
             if fleet == "uniform"
             else multi_gen_fleet().tightened(g.total_mem()))
-    gb = featurize(g, max_deg=8, topo=topo, csr=True)
+    gb = featurize(g, max_deg=8, topo=topo, scale=ScaleConfig(csr=True))
     cfg = PolicyConfig(hidden=32, gnn_layers=2, placer_layers=2, ffn=64,
-                       window=32, max_devices=8, segment=segment)
+                       window=32, max_devices=8,
+                       scale=ScaleConfig(segment=segment))
     cfg_k = dataclasses.replace(cfg, attn_impl="pallas_band",
                                 agg_impl="pallas_csr")
     params = P.init(jax.random.PRNGKey(0), cfg)
@@ -490,9 +492,11 @@ def test_ppo_iteration_with_kernel_impls():
     from repro.graphs import synthetic as S
     g = S.rnnlm(2, time_steps=3)
     task = C.make_task("kern-ppo", g, 4, segment=16)
-    gb = featurize(g, max_deg=8, topo=task.topo, pad_multiple=16, csr=True)
+    gb = featurize(g, max_deg=8, topo=task.topo,
+                   scale=ScaleConfig(pad_multiple=16, csr=True))
     pcfg = PolicyConfig(hidden=32, gnn_layers=2, placer_layers=2, ffn=64,
-                        window=32, max_devices=8, segment=16, gnn_chunk=16,
+                        window=32, max_devices=8,
+                        scale=ScaleConfig(segment=16, gnn_chunk=16),
                         attn_impl="pallas_band", agg_impl="pallas_csr")
     tr = PPOTrainer(pcfg, PPOConfig(num_samples=4, epochs=1), seed=0)
     m = tr.iteration(task.name, gb, task.env, task.num_devices)

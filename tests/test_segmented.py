@@ -22,6 +22,7 @@ from repro.core import gnn, placer as PL, policy as P
 from repro.core.featurize import featurize, jumbo_bucket
 from repro.core.policy import PolicyConfig
 from repro.core.ppo import PPOConfig, PPOTrainer
+from repro.core.scale import ScaleConfig
 from repro.graphs import synthetic as S
 from repro.sim import p100_topology
 from repro.sim.device import multi_gen_fleet
@@ -57,7 +58,8 @@ def test_sample_segmented_bitwise(setup, seg):
     logp as the monolithic scan — same step function, same keys, carried
     state across segment boundaries."""
     _, gb, params = setup
-    cfg_seg = dataclasses.replace(CFG, segment=seg, gnn_chunk=seg)
+    cfg_seg = dataclasses.replace(
+        CFG, scale=ScaleConfig(segment=seg, gnn_chunk=seg))
     key = jax.random.PRNGKey(1)
     pl_m, lp_m = P.sample(params, CFG, gb, 4, key, 3)
     pl_s, lp_s = P.sample(params, cfg_seg, gb, 4, key, 3)
@@ -71,7 +73,7 @@ def test_sample_segmented_bitwise_hetero(setup):
     g, _, params = setup
     topo = multi_gen_fleet().tightened(g.total_mem())
     gb = featurize(g, max_deg=8, topo=topo)
-    cfg_seg = dataclasses.replace(CFG, segment=SEG)
+    cfg_seg = dataclasses.replace(CFG, scale=ScaleConfig(segment=SEG))
     key = jax.random.PRNGKey(3)
     pl_m, lp_m = P.sample(params, CFG, gb, topo.num_devices, key, 2)
     pl_s, lp_s = P.sample(params, cfg_seg, gb, topo.num_devices, key, 2)
@@ -321,7 +323,7 @@ def test_logp_segmented_matches_monolithic(setup):
     """Policy-level PPO ratio path: per-node logp from the segmented TF
     pass equals the monolithic one to float tolerance on real nodes."""
     _, gb, params = setup
-    cfg_seg = dataclasses.replace(CFG, segment=SEG)
+    cfg_seg = dataclasses.replace(CFG, scale=ScaleConfig(segment=SEG))
     pl, _ = P.sample(params, CFG, gb, 4, jax.random.PRNGKey(4), 2)
     lp_m, ent_m = P.logp_and_entropy(params, CFG, gb, 4, pl)
     lp_s, ent_s = P.logp_and_entropy(params, cfg_seg, gb, 4, pl)
@@ -337,7 +339,8 @@ def test_gnn_chunked_bitwise(setup):
     _, gb, params = setup
     h0 = gnn.apply(params["gnn"], gb)
     for chunk in (8, 37, 64):
-        h1 = gnn.apply(params["gnn"], gb, chunk=chunk)
+        h1 = gnn.apply(params["gnn"], gb,
+                       scale=ScaleConfig(gnn_chunk=chunk))
         assert np.array_equal(np.asarray(h0), np.asarray(h1)), chunk
 
 
@@ -346,13 +349,14 @@ def test_gnn_chunked_bitwise_pallas(setup):
     densified path bit-for-bit (interpret mode on CPU)."""
     _, gb, params = setup
     h0 = gnn.apply(params["gnn"], gb, agg_impl="pallas")
-    h1 = gnn.apply(params["gnn"], gb, agg_impl="pallas", chunk=64)
+    h1 = gnn.apply(params["gnn"], gb, agg_impl="pallas",
+                   scale=ScaleConfig(gnn_chunk=64))
     assert np.array_equal(np.asarray(h0), np.asarray(h1))
 
 
 def test_featurize_pad_multiple():
     g = S.rnnlm(2, time_steps=3)
-    gb = featurize(g, max_deg=8, pad_multiple=64)
+    gb = featurize(g, max_deg=8, scale=ScaleConfig(pad_multiple=64))
     assert gb.op.shape[0] % 64 == 0
     assert gb.op.shape[0] >= g.num_nodes
     assert gb.num_nodes == g.num_nodes
@@ -413,7 +417,8 @@ def test_segmented_ppo_iteration_runs():
     end-to-end on a segment-padded task and produces finite, valid
     makespans."""
     from benchmarks import common as C
-    pcfg = dataclasses.replace(CFG, segment=SEG, gnn_chunk=SEG)
+    pcfg = dataclasses.replace(
+        CFG, scale=ScaleConfig(segment=SEG, gnn_chunk=SEG))
     ppo = PPOConfig(num_samples=4, epochs=1)
     g = S.rnnlm(2, time_steps=3)
     task = C.make_task("seg-ppo", g, 4, segment=SEG)
@@ -466,7 +471,7 @@ def test_mask_full_devices_feasible_and_exact():
     lp_tf, _ = P.logp_and_entropy(params, cfg_m, gb, 4, pl_m)
     assert float(jnp.abs(lp_m - lp_tf).max()) < 1e-4   # exact PPO ratios
 
-    cfg_ms = dataclasses.replace(cfg_m, segment=SEG)
+    cfg_ms = dataclasses.replace(cfg_m, scale=ScaleConfig(segment=SEG))
     pl_s, lp_s = P.sample(params, cfg_ms, gb, 4, jax.random.PRNGKey(1), 16)
     assert np.array_equal(np.asarray(pl_m), np.asarray(pl_s))
     assert np.array_equal(np.asarray(lp_m), np.asarray(lp_s))

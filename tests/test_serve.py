@@ -15,6 +15,7 @@ from repro.core.featurize import bucket_size, featurize
 from repro.core.graph import topo_relabel
 from repro.core.policy import PolicyConfig
 from repro.core.ppo import PPOConfig, PPOTrainer
+from repro.core.scale import ScaleConfig
 from repro.graphs import synthetic as S
 from repro.serve import (MicroBatcher, PlacementService, PlacementCache,
                          PersistentStore, ServeConfig, SimulatedClock,
@@ -327,19 +328,20 @@ def test_service_sheds_oversized_requests_typed():
 
 
 def test_service_jumbo_bucket_admission():
-    """Graphs above jumbo_threshold skip the micro-batcher: they are
+    """Graphs above scale.jumbo_threshold skip the micro-batcher: they are
     segment-padded (featurize.jumbo_bucket, not the power-of-two ladder)
     and served solo; the result is cached so repeats hit."""
     from repro.core.featurize import jumbo_bucket as jb
     pcfg = PolicyConfig(hidden=32, gnn_layers=2, placer_layers=1, ffn=64,
-                        window=32, max_devices=8, segment=32, gnn_chunk=64)
+                        window=32, max_devices=8,
+                        scale=ScaleConfig(segment=32, gnn_chunk=64,
+                                          jumbo_threshold=64,
+                                          jumbo_pad_multiple=64))
     trainer = PPOTrainer(pcfg, PPOConfig(num_samples=4, epochs=1), seed=0)
-    cfg = ServeConfig(simulated=True, num_samples=2,
-                      jumbo_threshold=64, jumbo_pad_multiple=64,
-                      finetune_iters=0)
+    cfg = ServeConfig(simulated=True, num_samples=2, finetune_iters=0)
     svc = PlacementService(trainer, cfg, SimulatedClock())
     g = S.rnnlm(2, time_steps=3)          # 72 nodes > 64 threshold
-    assert g.num_nodes > cfg.jumbo_threshold
+    assert g.num_nodes > pcfg.scale.jumbo_threshold
     topo = p100_topology(4).tightened(g.total_mem())
     r = svc.submit(g, topo, arrival_t=0.0)
     assert svc.counts["jumbo"] == 1
@@ -349,7 +351,7 @@ def test_service_jumbo_bucket_admission():
     # context arrays live at the segment-aligned jumbo bucket
     ctx = svc._ctx[r.key]
     assert ctx.gb.op.shape[0] == jb(g.num_nodes, 64)
-    assert ctx.gb.op.shape[0] % pcfg.segment == 0
+    assert ctx.gb.op.shape[0] % pcfg.scale.segment == 0
     # repeat traffic rides the cache, not another decode
     r2 = svc.submit(g, topo, arrival_t=1.0)
     assert r2.source == "cache"

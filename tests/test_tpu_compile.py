@@ -101,7 +101,7 @@ def test_tf_segment_band_compiles_for_v5e(one_chip, no_cache, monkeypatch):
     params = jax.eval_shape(lambda: P.init(jax.random.PRNGKey(0), cfg))
     pp = jax.tree_util.tree_map(
         lambda a: _spec(a.shape, a.dtype, one_chip), params["placer"])
-    s, hid, dmax = cfg.segment, cfg.hidden, cfg.max_devices
+    s, hid, dmax = cfg.scale.segment, cfg.hidden, cfg.max_devices
     hd = hid // cfg.heads
     f32 = jnp.float32
     mem = _spec((cfg.placer_layers, cfg.window - 1, cfg.heads, hd), f32,
